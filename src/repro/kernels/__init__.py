@@ -14,14 +14,16 @@ Kernels:
   secure_mask     — fused fixed-point encode + pairwise PRG mask-add for
                     masked secure aggregation (privacy engine)
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-# The TPU compiler-params dataclass was renamed across JAX releases
-# (TPUCompilerParams <-> CompilerParams). Resolve whichever this JAX has.
-_COMPILER_PARAMS_CLS = getattr(_pltpu, "CompilerParams", None) or getattr(
-    _pltpu, "TPUCompilerParams")
 
 
-def compiler_params(**kwargs):
-    """Version-compatible constructor for pltpu compiler params."""
-    return _COMPILER_PARAMS_CLS(**kwargs)
+def row_blocks(n_rows: int, want: int) -> tuple[int, int]:
+    """(block rows, padded row count) for a kernel tiled over rows.
+
+    The TPU lowering takes a block whose row count is a multiple of 8
+    sublanes or equal to the whole array's. So rows that fit in `want`
+    form one block, and longer arrays tile in `want`-row blocks (a
+    multiple of 8) with the row count padded up to a whole block."""
+    assert want % 8 == 0, want
+    if n_rows <= want:
+        return n_rows, n_rows
+    return want, -(-n_rows // want) * want

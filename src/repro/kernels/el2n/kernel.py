@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params
 
 LANES = 128
 NEG = -2.0 ** 30
@@ -103,7 +102,7 @@ def el2n_fwd(logits: jnp.ndarray, labels: jnp.ndarray, *,
             jax.ShapeDtypeStruct((N, LANES), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_n, LANES), jnp.float32)] * 4,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="sfprompt_el2n",

@@ -13,6 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import row_blocks
 from repro.kernels.el2n import ref
 from repro.kernels.el2n.kernel import el2n_fwd
 
@@ -53,14 +54,11 @@ def el2n_scores(logits: jnp.ndarray, labels: jnp.ndarray, *,
         return _fused_scores(logits, labels)
 
     N, V = logits.shape
-    bn = min(block_n, N) if N % min(block_n, N) == 0 else 1
-    # pick the largest block_n <= block_n dividing N
-    bn = next(b for b in (block_n, 128, 64, 32, 16, 8, 4, 2, 1) if N % b == 0)
+    bn, n_pad = row_blocks(N, block_n)
     bv = min(block_v, max(128, 1 << (V - 1).bit_length()))
-    padv = (-V) % bv
-    if padv:
-        logits = jnp.pad(logits, ((0, 0), (0, padv)))
+    logits = jnp.pad(logits, ((0, n_pad - N), (0, (-V) % bv)))
+    labels = jnp.pad(labels.astype(jnp.int32), (0, n_pad - N))
     el2n, ce = el2n_fwd(
-        logits, labels[:, None].astype(jnp.int32), vocab=V,
+        logits, labels[:, None], vocab=V,
         block_n=bn, block_v=bv, interpret=(impl == "interpret"))
-    return el2n[:, 0], ce[:, 0]
+    return el2n[:N, 0], ce[:N, 0]

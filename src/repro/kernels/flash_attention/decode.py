@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params
 from repro.kernels.flash_attention import ref
 
 MASK_VALUE = -2.0 ** 30
@@ -98,9 +97,10 @@ def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, kvpos_ref, o_ref,
                    n_kv_blocks: int):
     """One (slot, kv_head) pair; kv blocks innermost (sequential), carrying
     the online-softmax state in VMEM scratch. Block rows are the GQA group's
-    query heads for this kv head — a (group, block_kv) score tile."""
+    query heads for this kv head — a (group, block_kv) score tile.
+    `qpos_ref` is the scalar-prefetch operand of per-slot query positions."""
     _attend_kv_block(
-        pl.program_id(2), qpos_ref[0, 0], kvpos_ref[0],
+        pl.program_id(2), qpos_ref[pl.program_id(0)], kvpos_ref[0],
         q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         scale=scale, window=window, softcap=softcap, n_kv_blocks=n_kv_blocks)
 
@@ -116,7 +116,7 @@ def _paged_decode_kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref, kvpos_ref,
     already happened in the prefetch."""
     del bt_ref  # consumed by the index_maps
     _attend_kv_block(
-        pl.program_id(2), qpos_ref[pl.program_id(0)], kvpos_ref[...],
+        pl.program_id(2), qpos_ref[pl.program_id(0)], kvpos_ref[0],
         q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         scale=scale, window=window, softcap=softcap, n_kv_blocks=n_kv_blocks)
 
@@ -125,7 +125,7 @@ def decode_attention_fwd(
     q: jnp.ndarray,            # (B, Hkv, group, Dh) — grouped query heads
     k: jnp.ndarray,            # (B, Hkv, W, Dh)
     v: jnp.ndarray,            # (B, Hkv, W, Dv)
-    q_positions: jnp.ndarray,  # (B, 1) int32 — absolute query position
+    q_positions: jnp.ndarray,  # (B,) int32 — absolute query position
     kv_positions: jnp.ndarray,  # (B, 1, W) int32 — -1 marks empty slots
     *,
     scale: float,
@@ -143,27 +143,33 @@ def decode_attention_fwd(
         _decode_kernel, scale=scale, window=sliding_window, softcap=softcap,
         n_kv_blocks=nkv)
 
-    return pl.pallas_call(
-        kernel,
+    # the query positions ride in by scalar prefetch (SMEM), like the paged
+    # kernel's: a (1, 1) VMEM block of a (B, 1) array is not a legal tile
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, Hkv, nkv),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ikv: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, G, Dh), lambda b, h, ikv: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, G, Dh), lambda b, h, ikv, qp: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_kv, Dh),
-                         lambda b, h, ikv: (b, h, ikv, 0)),
+                         lambda b, h, ikv, qp: (b, h, ikv, 0)),
             pl.BlockSpec((1, 1, block_kv, Dv),
-                         lambda b, h, ikv: (b, h, ikv, 0)),
-            pl.BlockSpec((1, 1, block_kv), lambda b, h, ikv: (b, 0, ikv)),
+                         lambda b, h, ikv, qp: (b, h, ikv, 0)),
+            pl.BlockSpec((1, 1, block_kv),
+                         lambda b, h, ikv, qp: (b, 0, ikv)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, Dv), lambda b, h, ikv: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, Dv),
+                               lambda b, h, ikv, qp: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, LANES), jnp.float32),   # m
             pltpu.VMEM((G, LANES), jnp.float32),   # l
             pltpu.VMEM((G, Dv), jnp.float32),      # acc
         ],
-        compiler_params=compiler_params(
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="sfprompt_decode_attention",
@@ -251,9 +257,9 @@ def decode_attention(
         vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad), (0, 0)))
         kvp = jnp.pad(kvp, ((0, 0), (0, 0), (0, pad)), constant_values=-1)
     out = decode_attention_fwd(
-        qg, kt, vt, q_positions.astype(jnp.int32)[:, None],
-        kvp.astype(jnp.int32), scale=scale, sliding_window=sliding_window,
-        softcap=softcap, block_kv=bkv, interpret=(impl == "interpret"))
+        qg, kt, vt, q_positions.astype(jnp.int32), kvp.astype(jnp.int32),
+        scale=scale, sliding_window=sliding_window, softcap=softcap,
+        block_kv=bkv, interpret=(impl == "interpret"))
     return out.reshape(B, 1, Hq, Dv)
 
 
@@ -264,7 +270,7 @@ def paged_decode_attention_fwd(
     v_pool: jnp.ndarray,        # (P, Hkv, page, Dv)
     block_tables: jnp.ndarray,  # (B, n_blocks) int32 physical page ids
     q_positions: jnp.ndarray,   # (B,) int32 — absolute query position
-    kv_positions: jnp.ndarray,  # (P, page) int32 — -1 marks empty slots
+    kv_positions: jnp.ndarray,  # (P, 1, page) int32 — -1 marks empty
     *,
     scale: float,
     sliding_window: Optional[int],
@@ -292,8 +298,10 @@ def paged_decode_attention_fwd(
                          lambda b, h, i, bt, qp: (bt[b, i], h, 0, 0)),
             pl.BlockSpec((1, 1, page, Dv),
                          lambda b, h, i, bt, qp: (bt[b, i], h, 0, 0)),
-            pl.BlockSpec((1, page),
-                         lambda b, h, i, bt, qp: (bt[b, i], 0)),
+            # (1, 1, page) over (P, 1, page): the last two block dims equal
+            # the array's, as the dense kernel's (B, 1, W) positions do
+            pl.BlockSpec((1, 1, page),
+                         lambda b, h, i, bt, qp: (bt[b, i], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, Dv),
                                lambda b, h, i, bt, qp: (b, h, 0, 0)),
@@ -307,7 +315,7 @@ def paged_decode_attention_fwd(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="sfprompt_paged_decode_attention",
@@ -370,7 +378,7 @@ def paged_decode_attention(
     kt = jnp.moveaxis(k, 2, 1)                   # (P, Hkv, page, Dh)
     vt = jnp.moveaxis(v, 2, 1)
     out = paged_decode_attention_fwd(
-        qg, kt, vt, block_tables, q_positions, kv_positions, scale=scale,
-        sliding_window=sliding_window, softcap=softcap,
+        qg, kt, vt, block_tables, q_positions, kv_positions[:, None, :],
+        scale=scale, sliding_window=sliding_window, softcap=softcap,
         interpret=(impl == "interpret"))
     return out.reshape(B, 1, Hq, Dv)

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params
 
 MASK_VALUE = -2.0 ** 30
 LANES = 128
@@ -139,7 +138,7 @@ def flash_attention_fwd(
             pltpu.VMEM((block_q, LANES), jnp.float32),   # l
             pltpu.VMEM((block_q, Dv), jnp.float32),      # acc
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="sfprompt_flash_attention",

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params
-
 
 def _mamba2_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,
                    y_ref, hout_ref, h_scr, *,
@@ -124,7 +122,7 @@ def mamba2_fwd(
             jax.ShapeDtypeStruct((BH, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="sfprompt_mamba2_ssd",

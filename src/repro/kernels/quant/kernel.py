@@ -21,8 +21,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params
 
 LANES = 128
 EPS = 1e-8
@@ -63,7 +63,7 @@ def quantize_fwd(x: jnp.ndarray, u: jnp.ndarray, *, block_n: int = 256,
             jax.ShapeDtypeStruct((N, D), jnp.int8),
             jax.ShapeDtypeStruct((N, LANES), jnp.float32),
         ],
-        compiler_params=compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
         name="sfprompt_wire_quantize",
     )(x, u)
@@ -85,7 +85,7 @@ def dequantize_fwd(values: jnp.ndarray, scales: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((block_n, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, D), dtype),
-        compiler_params=compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
         name="sfprompt_wire_dequantize",
     )(values, scales)

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import row_blocks
 from repro.kernels.quant import ref
 from repro.kernels.quant.kernel import LANES, dequantize_fwd, quantize_fwd
 
@@ -16,8 +17,7 @@ def _resolve(impl: str) -> str:
     return impl
 
 
-def _block_n(N: int, want: int = 256) -> int:
-    return next(b for b in (want, 128, 64, 32, 16, 8, 4, 2, 1) if N % b == 0)
+BLOCK_ROWS = 256
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
@@ -33,13 +33,12 @@ def quantize_int8(x: jnp.ndarray, u: jnp.ndarray, *, impl: str = "auto"):
         return ref.quantize(x, u)
     N, D = x.shape
     u = jnp.broadcast_to(jnp.asarray(u, jnp.float32), x.shape)
-    padd = (-D) % LANES
-    if padd:
-        x = jnp.pad(x, ((0, 0), (0, padd)))
-        u = jnp.pad(u, ((0, 0), (0, padd)))
-    values, scales = quantize_fwd(x, u, block_n=_block_n(N),
+    block_n, n_pad = row_blocks(N, BLOCK_ROWS)
+    pad = ((0, n_pad - N), (0, (-D) % LANES))
+    values, scales = quantize_fwd(jnp.pad(x, pad), jnp.pad(u, pad),
+                                  block_n=block_n,
                                   interpret=(impl == "interpret"))
-    return values[:, :D], scales[:, :1]
+    return values[:N, :D], scales[:N, :1]
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "dtype"))
@@ -50,10 +49,11 @@ def dequantize_int8(values: jnp.ndarray, scales: jnp.ndarray, *,
     if impl == "ref":
         return ref.dequantize(values, scales, dtype)
     N, D = values.shape
-    padd = (-D) % LANES
-    if padd:
-        values = jnp.pad(values, ((0, 0), (0, padd)))
-    scales = jnp.broadcast_to(scales.astype(jnp.float32), (N, LANES))
-    out = dequantize_fwd(values, scales, dtype=dtype, block_n=_block_n(N),
+    block_n, n_pad = row_blocks(N, BLOCK_ROWS)
+    values = jnp.pad(values, ((0, n_pad - N), (0, (-D) % LANES)))
+    scales = jnp.pad(
+        jnp.broadcast_to(scales.astype(jnp.float32), (N, LANES)),
+        ((0, n_pad - N), (0, 0)))
+    out = dequantize_fwd(values, scales, dtype=dtype, block_n=block_n,
                          interpret=(impl == "interpret"))
-    return out[:, :D]
+    return out[:N, :D]

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params
 from repro.kernels.secure_mask.ref import FRAC_BITS, SAT
 
 LANES = 128
@@ -35,8 +34,9 @@ def _masked_encode_kernel(seeds_ref, signs_ref, x_ref, o_ref, *,
     # ---- fixed-point encode (saturating two's complement)
     q = jnp.round(x_ref[...].astype(jnp.float32) * (2.0 ** frac_bits))
     q = jnp.clip(q, -SAT, SAT)
-    mag = jnp.abs(q).astype(jnp.uint32)
-    acc = jnp.where(q < 0, jnp.uint32(0) - mag, mag)
+    # |q| <= SAT < 2^31, so the int32 cast is exact and its two's-complement
+    # bits are the ring value (the TPU has no float -> uint32 cast)
+    acc = pltpu.bitcast(q.astype(jnp.int32), jnp.uint32)
 
     # ---- fold in each pairwise mask stream, generated on-core
     blk = pl.program_id(0)
@@ -68,7 +68,7 @@ def masked_encode_fwd(x: jnp.ndarray, seeds: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, D), jnp.uint32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="sfprompt_secure_masked_encode",

@@ -14,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import row_blocks
 from repro.kernels.secure_mask import ref
 from repro.kernels.secure_mask.kernel import LANES, masked_encode_fwd
 from repro.kernels.secure_mask.ref import (FRAC_BITS, decode,  # noqa: F401
@@ -33,11 +34,21 @@ def ring_size(n: int) -> int:
     return n + (-n) % LANES
 
 
-def _block_n(N: int, want: int = 8) -> int:
-    """Largest row-block that divides N exactly — a remainder would leave
-    trailing rows unwritten (the grid floor-divides), and N can be as
-    small as 1 (one LANES-row upload)."""
-    return next(b for b in (want, 4, 2, 1) if N % b == 0)
+BLOCK_ROWS = 512
+
+
+def _pallas_masked_encode(x, seeds, signs, frac_bits, interpret):
+    """The kernel over the (n // LANES, LANES) view of x, its rows padded
+    up to whole blocks and sliced back. The block size is a pure function
+    of n, so the uploads and the server's recovery pass draw the same
+    per-block streams."""
+    n = x.shape[0]
+    rows = n // LANES
+    block_n, n_rows = row_blocks(rows, BLOCK_ROWS)
+    x2 = jnp.pad(x.reshape(rows, LANES), ((0, n_rows - rows), (0, 0)))
+    out = masked_encode_fwd(x2, seeds, signs, frac_bits=frac_bits,
+                            block_n=block_n, interpret=interpret)
+    return out[:rows].reshape(n)
 
 
 @functools.partial(jax.jit, static_argnames=("frac_bits", "impl"))
@@ -51,12 +62,8 @@ def masked_encode(x: jnp.ndarray, seeds: jnp.ndarray, signs: jnp.ndarray, *,
     impl = _resolve(impl)
     if impl == "ref":
         return ref.masked_encode(x, seeds, signs, frac_bits)
-    n = x.shape[0]
-    x2 = x.reshape(-1, LANES)
-    out = masked_encode_fwd(x2, seeds, signs, frac_bits=frac_bits,
-                            block_n=_block_n(x2.shape[0]),
-                            interpret=(impl == "interpret"))
-    return out.reshape(n)
+    return _pallas_masked_encode(x, seeds, signs, frac_bits,
+                                 interpret=(impl == "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("n", "frac_bits", "impl"))
@@ -67,8 +74,5 @@ def summed_mask(seeds: jnp.ndarray, signs: jnp.ndarray, n: int, *,
     impl = _resolve(impl)
     if impl == "ref":
         return ref.summed_mask(seeds, signs, n)
-    out = masked_encode_fwd(jnp.zeros((n // LANES, LANES), jnp.float32),
-                            seeds, signs, frac_bits=frac_bits,
-                            block_n=_block_n(n // LANES),
-                            interpret=(impl == "interpret"))
-    return out.reshape(n)
+    return _pallas_masked_encode(jnp.zeros((n,), jnp.float32), seeds, signs,
+                                 frac_bits, interpret=(impl == "interpret"))

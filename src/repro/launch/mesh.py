@@ -12,6 +12,7 @@ this module never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.sharding.rules import report_fallbacks
 
@@ -21,10 +22,18 @@ HBM_BW = 819e9                 # bytes/s
 ICI_BW = 50e9                  # bytes/s per link
 
 
+def _mesh(shape, axes, devices=None):
+    """A mesh whose axes the compiler partitions (GSPMD): params and
+    cohorts are placed by explicit in/out shardings, and the computations
+    between them need no sharding in their types."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(n: int = 0, *, model: int = 1):
@@ -46,13 +55,12 @@ def make_host_mesh(n: int = 0, *, model: int = 1):
             "XLA_FLAGS=--xla_force_host_platform_device_count before jax "
             "initializes")
     if model <= 1:
-        return jax.make_mesh((n,), ("data",), devices=devices[:n])
+        return _mesh((n,), ("data",), devices[:n])
     if n % model != 0:
         raise ValueError(
             f"model={model} does not divide the {n}-device host mesh — "
             "pick a model-axis size that divides the device count")
-    return jax.make_mesh((n // model, model), ("data", "model"),
-                         devices=devices[:n])
+    return _mesh((n // model, model), ("data", "model"), devices[:n])
 
 
 def report_sharding_fallbacks(context: str = "", tracer=None) -> tuple:

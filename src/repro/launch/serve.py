@@ -32,6 +32,7 @@ fewer prefill bytes, so measured <= analytical when sharing kicks in.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +41,8 @@ from repro.checkpoint import load_checkpoint
 from repro.configs import get_config
 from repro.core import SplitConfig, SplitModel
 from repro.core.comm import serve_comm_breakdown
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models.config import ModelConfig
 from repro.obs import MetricsRegistry, export_all, make_tracer
 from repro.obs.trace import LEVELS
 from repro.runtime import WireSpec
@@ -73,11 +76,32 @@ def personalized_bank(model: SplitModel, params, n_tenants: int,
     return TenantBank.from_lists(tails, prompts)
 
 
+def served_config(arch: str, *, reduced: bool = True,
+                  layers: int | None = None) -> ModelConfig:
+    """The reduced same-family config, or the published widths with only
+    the depth cut to `layers` (None keeps the published depth)."""
+    cfg = get_config(arch)
+    if reduced:
+        # at least 3 layer-pattern cycles so head/body/tail are all non-empty
+        return cfg.reduced(n_layers=layers or 3 * len(cfg.layer_pattern))
+    if layers:
+        return dataclasses.replace(cfg, name=f"{cfg.name}-{layers}L",
+                                   n_layers=layers)
+    return cfg
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-14b")
-    ap.add_argument("--reduced", action="store_true", default=True,
-                    help="CPU-sized same-family config (on by default)")
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="CPU-sized same-family config (on by default; "
+                         "--no-reduced serves the published widths)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="layer count: with --reduced, the reduced config's "
+                         "(default three layer-pattern cycles); with "
+                         "--no-reduced, cuts only the depth of the published "
+                         "config (default: its full depth)")
     ap.add_argument("--requests", type=int, default=16,
                     help="synthetic workload length")
     ap.add_argument("--slots", type=int, default=8,
@@ -122,6 +146,9 @@ def main(argv=None):
                          "mesh with a 'model' axis of this size (frozen "
                          "body sharded, KV kv-heads sharded; must divide "
                          "the visible device count; 1 = single-device)")
+    ap.add_argument("--collect-logits", action="store_true",
+                    help="keep every generated token's logits in the "
+                         "returned per-request results (to compare runs)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--params", default=None,
                     help="checkpoint to serve (e.g. a training run's "
@@ -144,10 +171,8 @@ def main(argv=None):
                          "TraceAnnotations (visible in a profiler capture)")
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        # at least 3 layer-pattern cycles so head/body/tail are all non-empty
-        cfg = cfg.reduced(n_layers=3 * len(cfg.layer_pattern))
+    enable_compile_cache()
+    cfg = served_config(args.arch, reduced=args.reduced, layers=args.layers)
     split = SplitConfig(head_cycles=1, tail_cycles=1, prompt_len=4)
     wire = WireSpec.make(args.wire)
     model = SplitModel(cfg, split, wire)
@@ -162,6 +187,9 @@ def main(argv=None):
     tracer = make_tracer(trace_level, profiler=args.trace_profiler)
 
     bank = personalized_bank(model, params, args.tenants)
+    # the bank holds the tails: drop the init's own tail so one copy stays
+    shared = {"head": params["head"], "body": params["body"]}
+    del params
     mesh = None
     if args.mesh_model > 1:
         from repro.launch.mesh import make_host_mesh
@@ -172,7 +200,7 @@ def main(argv=None):
         prefix = tuple(int(1 + (i * 13) % (cfg.vocab_size - 1))
                        for i in range(args.shared_prefix))
         engine = PagedServeEngine(
-            model, params, bank,
+            model, shared, bank,
             PagedServeConfig(n_slots=args.slots, max_seq=args.max_seq,
                              decode_block=args.decode_block,
                              donate=not args.no_donate, impl=args.impl,
@@ -180,17 +208,18 @@ def main(argv=None):
                              n_pages=args.n_pages,
                              shared_prefix=prefix or None,
                              prefill_chunk=args.prefill_chunk),
-            mesh=mesh, tracer=tracer)
+            collect_logits=args.collect_logits, mesh=mesh, tracer=tracer)
     else:
         if args.shared_prefix or args.prefill_chunk:
             raise SystemExit("--shared-prefix/--prefill-chunk need the "
                              "paged engine: pass --page-size N")
-        engine = ServeEngine(model, params, bank,
+        engine = ServeEngine(model, shared, bank,
                              ServeConfig(n_slots=args.slots,
                                          max_seq=args.max_seq,
                                          decode_block=args.decode_block,
                                          donate=not args.no_donate,
                                          impl=args.impl),
+                             collect_logits=args.collect_logits,
                              mesh=mesh, tracer=tracer)
     reqs = synthetic_requests(WorkloadConfig(
         n_requests=args.requests,
@@ -258,6 +287,8 @@ def main(argv=None):
         import json as _json
         print(_json.dumps({"metrics": registry.snapshot()}, sort_keys=True,
                           default=str), flush=True)
+    stats["wire_analytical"] = analytical
+    stats["engine"] = engine
     return stats
 
 

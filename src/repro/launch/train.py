@@ -49,6 +49,7 @@ partial aggregation).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import time
@@ -69,6 +70,7 @@ from repro.fed import (AsyncConfig, AsyncRoundEngine, ClientSampler,
                        FederatedEngine, Population, RoundScheduler,
                        StragglerConfig)
 from repro.fed.scheduler import LINK_REGIMES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import MetricsRegistry, export_all, make_tracer
 from repro.obs.trace import LEVELS
 
@@ -206,7 +208,9 @@ def build_engine(args, trainer, population, cfg, split):
                            personalize_tails=args.personalize_tails)
 
 
-def main():
+def main(argv=None):
+    """Run the launcher on `argv` (default: the command line). Returns the
+    engine, the trainer and the per-round log records."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="vit-base")
     ap.add_argument("--reduced", action="store_true",
@@ -310,7 +314,7 @@ def main():
     ap.add_argument("--trace-profiler", action="store_true",
                     help="wrap traced device dispatches in jax.profiler "
                          "TraceAnnotations (visible in a profiler capture)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.personalize_tails and not args.method.startswith("sfprompt"):
         ap.error("--personalize-tails needs an sfprompt method")
     if ((args.dropout_rate > 0 or args.straggle)
@@ -347,9 +351,15 @@ def main():
             ap.error("--async-buffer with --edges is not supported: the "
                      "flush cohort is the buffer, not an edge layout")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced(n_layers=args.layers)
+    if args.dataset != "lm-syn":
+        # the classifier is the task's: one logit per class of the data
+        # (a reduced config's 10-way head would give NaN losses on 100)
+        cfg = dataclasses.replace(
+            cfg, num_classes=DATASETS[args.dataset].n_classes)
     split = SplitConfig(head_cycles=args.head_cycles,
                         tail_cycles=args.tail_cycles,
                         prompt_len=args.prompt_len, prune_gamma=args.gamma,
@@ -419,22 +429,25 @@ def main():
             f.writelines(kept)
     log = open(log_path, "a" if resumed else "w")
 
+    records = []
     t0 = time.time()
     while progress() < args.rounds:
         r = progress()
+        t_round = time.perf_counter()
         if is_async:
             metrics = engine.run_flushes(1)
             metrics["t_sim"] = engine.t_sim
-            rec = {"round": r, "wall_s": round(time.time() - t0, 1),
-                   **metrics}
         else:
             plan, metrics = engine.run_round()
-            rec = {"round": r, "wall_s": round(time.time() - t0, 1),
-                   "cohort": plan.cohort.tolist(), **metrics}
+            metrics["cohort"] = plan.cohort.tolist()
+        jax.block_until_ready(engine.params)
+        rec = {"round": r, "wall_s": round(time.time() - t0, 1),
+               "round_s": time.perf_counter() - t_round, **metrics}
         if hasattr(trainer, "evaluate"):
             ev = trainer.evaluate(engine.params, test,
                                   batch_size=args.batch_size)
             rec.update({f"eval_{k}": v for k, v in ev.items()})
+        records.append(rec)
         log.write(json.dumps(rec) + "\n")
         log.flush()
         print(rec, flush=True)
@@ -465,6 +478,8 @@ def main():
     accountant = getattr(trainer, "accountant", None)
     if accountant is not None:
         print(accountant.report())
+    log.close()
+    return {"engine": engine, "trainer": trainer, "records": records}
 
 
 if __name__ == "__main__":

@@ -311,8 +311,6 @@ class PagedServeEngine(ServeEngine):
     def _admit_one(self, req: Request) -> Optional[Finished]:
         ps = self.cfg.page_size
         nb_total = self._n_blocks(req)
-        tail = self.bank.tail(req.tenant)
-        prompt = self.bank.prompt(req.tenant)
         tokens_np = np.asarray(req.tokens, np.int32)
         slot = self._free.pop()
         table = np.full((self.nb_max,), PagePool.NULL_PAGE, np.int32)
@@ -326,7 +324,9 @@ class PagedServeEngine(ServeEngine):
             self._note_alloc()
             table[:nb_total] = pages
             mask[:nb_total] = True
-            tok, logits, cache = self._run_prefill(tail, prompt, tokens_np)
+            tok, logits, cache = self._run_prefill(
+                self.bank.tail(req.tenant), self.bank.prompt(req.tenant),
+                tokens_np)
             next_pos = len(req.tokens) + self.model.split.prompt_len
         else:
             entry = self._prefix.get(req.tenant)
@@ -362,8 +362,10 @@ class PagedServeEngine(ServeEngine):
             self._slot_shared[slot] = req.tenant
             cache = self._gather_slot(self.pool, jnp.asarray(table),
                                       jnp.int32(L_pre))
-            tok, logits, cache = self._run_chunks(tail, tokens_np, cache,
-                                                  L_pre)
+            # the tenant's tail is sliced out of the bank only after any
+            # prefix build has dropped its own slice: one copy at a time
+            tok, logits, cache = self._run_chunks(self.bank.tail(req.tenant),
+                                                  tokens_np, cache, L_pre)
             next_pos = L_pre + len(req.tokens)
 
         self._tables[slot] = table

@@ -15,7 +15,8 @@ import pytest
 from repro.configs import get_config
 from repro.core import SplitConfig, SplitModel
 from repro.core.comm import serve_comm_breakdown
-from repro.kernels.flash_attention.decode import decode_attention
+from repro.kernels.flash_attention.decode import (decode_attention,
+                                                  paged_decode_attention)
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.runtime import WireSpec
 from repro.serve import (Request, ServeConfig, ServeEngine, TenantBank,
@@ -133,27 +134,62 @@ def _ragged_cache(B, W, Hkv, D, lens, *, ring=False):
     return k, v, jnp.asarray(pos)
 
 
+def _as_page_pool(k, v, pos, page):
+    """The dense (B, W) caches scattered into a shuffled page pool with the
+    two reserved pages in front: (k_pool, v_pool, block_tables, pool_pos)."""
+    B, W = pos.shape
+    nb = W // page
+    P = 2 + B * nb
+    perm = np.random.default_rng(7).permutation(np.arange(2, P))
+    tables = perm.reshape(B, nb)
+
+    def pool(x):
+        out = np.zeros((P, page) + x.shape[2:], np.asarray(x).dtype)
+        out[tables] = np.asarray(x).reshape((B, nb, page) + x.shape[2:])
+        return out
+
+    pool_pos = np.full((P, page), -1, np.int32)
+    pool_pos[tables] = np.asarray(pos).reshape(B, nb, page)
+    return (jnp.asarray(pool(k)), jnp.asarray(pool(v)),
+            jnp.asarray(tables, jnp.int32), jnp.asarray(pool_pos))
+
+
 @pytest.mark.parametrize("Hq,Hkv", [(4, 4), (8, 2), (4, 1)])
 @pytest.mark.parametrize("kw", [
     {},
     dict(sliding_window=16),
     dict(softcap=10.0),
     dict(sliding_window=9, softcap=5.0),
+    # a window that is not a block multiple: several kv blocks per slot,
+    # the query positions read from the scalar-prefetch operand
+    dict(W=300),
+    # the paged layout: (P, 1, page) pool positions, block tables
+    dict(page=16),
+    dict(page=16, sliding_window=9, softcap=5.0),
 ])
 def test_decode_attention_kernel_vs_ref(Hq, Hkv, kw):
     """Pallas decode kernel (interpret) and the grouped XLA path vs the
-    jnp oracle, across GQA ratios, sliding windows, softcap, and ragged
-    ring-ordered per-slot lengths."""
-    B, W, D = 3, 64, 32
-    lens = [7, 33, 64]
+    jnp oracle, across GQA ratios, sliding windows, softcap, ragged
+    ring-ordered per-slot lengths, multi-block windows and the paged
+    pool."""
+    kw = dict(kw)
+    W, page = kw.pop("W", 64), kw.pop("page", None)
+    B, D = 3, 32
+    lens = [7, 33, W]
     q = jax.random.normal(jax.random.PRNGKey(0), (B, 1, Hq, D))
     k, v, pos = _ragged_cache(B, W, Hkv, D, lens, ring=True)
     qpos = jnp.asarray([L - 1 for L in lens], jnp.int32)
     ref = decode_attention(q, k, v, q_positions=qpos, kv_positions=pos,
                            impl="ref", **kw)
     for impl in ("xla", "interpret"):
-        out = decode_attention(q, k, v, q_positions=qpos, kv_positions=pos,
-                               impl=impl, **kw)
+        if page is None:
+            out = decode_attention(q, k, v, q_positions=qpos,
+                                   kv_positions=pos, impl=impl, **kw)
+        else:
+            k_pool, v_pool, tables, pool_pos = _as_page_pool(k, v, pos, page)
+            out = paged_decode_attention(
+                q, k_pool, v_pool, block_tables=tables, q_positions=qpos,
+                kv_positions=pool_pos, impl=impl, **kw)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5,
                                    err_msg=f"impl={impl} {kw}")

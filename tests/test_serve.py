@@ -1,6 +1,8 @@
 """Serving-engine correctness: continuous batching is logit-equivalent to
 sequential decoding, tenants are isolated, and the metered wire traffic
 matches the analytical per-token model."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -239,3 +241,37 @@ def test_engine_rejects_non_token_archs():
                     TenantBank.replicate(params["tail"], params["prompt"],
                                          2),
                     ServeConfig(n_slots=2, max_seq=32))
+
+
+def test_launcher_cuts_depth_only_at_published_widths():
+    from repro.launch.serve import served_config
+    pub = get_config("qwen2.5-14b")
+    cut = served_config("qwen2.5-14b", reduced=False, layers=3)
+    assert cut.n_layers == 3 and cut.name == "qwen2.5-14b-3L"
+    assert dataclasses.replace(cut, name=pub.name,
+                               n_layers=pub.n_layers) == pub
+    assert served_config("qwen2.5-14b", reduced=False) == pub
+    small = served_config("qwen2.5-14b")
+    assert small.n_layers == 3 and small.d_model < pub.d_model
+
+
+def test_launcher_paged_bytes_exact_with_logits():
+    """The launcher's paged run meters the analytical bytes less exactly
+    one [soft prompt | prefix] payload per prefix hit, and hands back each
+    request's logits and its engine."""
+    from repro.launch import serve
+    stats = serve.main([
+        "--requests", "6", "--slots", "2", "--tenants", "1", "--wire",
+        "int8", "--prompt-choices", "8", "12", "--new-token-choices", "2",
+        "4", "--page-size", "8", "--shared-prefix", "11",
+        "--prefill-chunk", "4", "--collect-logits", "--impl", "ref"])
+    model = stats["engine"].model
+    hit = serve_comm_breakdown(model.wire, d_model=model.cfg.d_model,
+                               soft_prompt_len=model.split.prompt_len,
+                               requests=[(11, 1)])
+    assert stats["prefix_hits"] > 0
+    for name, want in stats["wire_analytical"].items():
+        assert stats["wire_bytes"][name] == \
+            want - stats["prefix_hits"] * hit[name]
+    for f in stats["finished"]:
+        assert f.logits.shape == (f.req.max_new, model.cfg.vocab_size)

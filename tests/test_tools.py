@@ -176,3 +176,15 @@ def test_docs_check_fails_on_missing_doc(docs_check, tmp_path, monkeypatch):
     monkeypatch.setattr(docs_check, "ROOT", tmp_path)
     monkeypatch.setattr(docs_check, "CHECKED_DOCS", ("docs/ABSENT.md",))
     assert docs_check.main() == 1
+
+
+def test_docs_check_knows_negated_boolean_flags(docs_check, tmp_path,
+                                                monkeypatch):
+    mini_repo(tmp_path)
+    (tmp_path / "src" / "repro" / "launch" / "serve.py").write_text(
+        'ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,'
+        '\n                default=True)\n')
+    monkeypatch.setattr(docs_check, "ROOT", tmp_path)
+    flags = docs_check.defined_flags()
+    assert {"--reduced", "--no-reduced", "--rounds"} <= flags
+    assert "--no-rounds" not in flags

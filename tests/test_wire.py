@@ -60,9 +60,12 @@ def test_int8_stochastic_rounding_unbiased():
     assert float(jnp.max(jnp.abs(bias) / scales)) < 0.2
 
 
-def test_int8_kernel_matches_ref_bitwise():
+# 12 and 20 rows (a batch-1 prefill) are not multiples of 8; 300 rows
+# spans two padded row blocks
+@pytest.mark.parametrize("rows", [40, 12, 20, 300])
+def test_int8_kernel_matches_ref_bitwise(rows):
     """Pallas (interpret) quant/dequant == pure-jnp ref on the same noise."""
-    x = jax.random.normal(KEY, (40, 96)) * 3
+    x = jax.random.normal(KEY, (rows, 96)) * 3
     u = jax.random.uniform(jax.random.fold_in(KEY, 1), x.shape)
     vr, sr = quantize_int8(x, u, impl="ref")
     vi, si = quantize_int8(x, u, impl="interpret")

@@ -39,7 +39,7 @@ CHECKED_DOCS = (
 )
 
 # where CLI flags may legitimately be defined
-FLAG_SOURCES = ("src/repro/launch", "benchmarks", "tools")
+FLAG_SOURCES = (".", "src/repro/launch", "benchmarks", "tools")
 
 # bare filenames (no directory part) are searched here, in order
 BARE_NAME_DIRS = ("", "docs", "examples", "tools", "benchmarks")
@@ -65,8 +65,13 @@ def defined_flags() -> set:
     flags = set()
     for d in FLAG_SOURCES:
         for py in (ROOT / d).glob("*.py"):
+            text = py.read_text()
             flags |= set(re.findall(
-                r"add_argument\(\s*[\"'](--[\w-]+)[\"']", py.read_text()))
+                r"add_argument\(\s*[\"'](--[\w-]+)[\"']", text))
+            # a BooleanOptionalAction also defines --no-<flag>
+            flags |= {"--no-" + f for f in re.findall(
+                r"add_argument\(\s*[\"']--([\w-]+)[\"'],\s*"
+                r"action=argparse\.BooleanOptionalAction", text)}
     return flags
 
 
