@@ -1,0 +1,96 @@
+"""Compile each cell's programs for a described TPU v5e, without the chip,
+and print what the compiler says they need.
+
+    JAX_PLATFORMS=cpu python3 bench/rehearse.py [cell ...]
+
+For each cell: the federated round at the cell's cohort (clear or secure
+aggregation). Every argument is a shape on one described chip; nothing runs. Kernels are asked
+for by name (`impl="pallas"`), since code that asks which backend it is on
+sees the CPU here. Prints one JSON line per program with
+`memory_analysis()` in bytes.
+"""
+from __future__ import annotations
+
+import json
+import os
+import sys
+from pathlib import Path
+
+sys.path[:0] = [str(Path(__file__).resolve().parents[1]),
+                str(Path(__file__).resolve().parents[1] / "src")]
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from bench import common  # noqa: E402
+
+
+def memory(compiled) -> dict:
+    m = compiled.memory_analysis()
+    return {k: int(getattr(m, k)) for k in (
+        "argument_size_in_bytes", "output_size_in_bytes",
+        "temp_size_in_bytes", "alias_size_in_bytes",
+        "generated_code_size_in_bytes")}
+
+
+def on(chip, tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                                       sharding=chip), tree)
+
+
+def federated(spec, chip):
+    from bench.systems import federated as fed
+    from repro.core import ProtocolConfig, SFPromptTrainer, SplitConfig
+    from repro.core.aggregation import get_aggregator
+    from repro.core.split import SplitModel
+    from repro.runtime import WireSpec
+
+    cfg, mix = spec["config"], spec["traffic"]
+    ref = common.load_module(spec["reference"])
+    sp, prog = cfg["split"], cfg["program"]
+    model = SplitModel(fed.model_config(cfg), SplitConfig(
+        head_cycles=sp["head_layers"], tail_cycles=sp["tail_layers"],
+        prompt_len=sp["prompt_len"], prune_gamma=sp["prune_gamma"]),
+        WireSpec.make(cfg["wire"], impl="pallas"))
+    trainer = SFPromptTrainer(model, ProtocolConfig(
+        clients_per_round=mix["cohort"], local_epochs=mix["local_epochs"],
+        batch_size=mix["batch"], lr_local=prog["lr_local"],
+        lr_split=prog["lr_split"], momentum=prog["momentum"],
+        impl=prog["impl"]),
+        get_aggregator(secure=True, impl="pallas", seed=mix["mask_seed"],
+                       frac_bits=mix["frac_bits"]) if mix["secure"]
+        else None)
+    params = jax.eval_shape(lambda k: ref.to_program(
+        ref.make_weights(cfg, k), cfg), jax.random.PRNGKey(0))
+    s = ref.sizes(cfg)
+    K, n = mix["cohort"], mix["samples_per_client"]
+    f32 = jnp.float32
+    state = {"params": params, "round": jax.ShapeDtypeStruct((), jnp.int32)}
+    data = {"patches": jax.ShapeDtypeStruct((K, n, s["N"], s["P"]), f32),
+            "labels": jax.ShapeDtypeStruct((K, n), jnp.int32)}
+    part = {"transmit": jax.ShapeDtypeStruct((K,), f32),
+            "aggregate": jax.ShapeDtypeStruct((K,), f32)}
+    compiled = jax.jit(trainer._round).lower(
+        on(chip, state), on(chip, data), on(chip, part), None).compile()
+    yield f"round K={K} {'secure' if mix['secure'] else 'clear'}", compiled
+
+
+def main(argv) -> int:
+    from jax.experimental import topologies
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    bench = common.benchmark()
+    cells = argv or [w["name"] for w in bench["workloads"]]
+    for cell in cells:
+        spec = common.resolve(cell, bench)
+        for what, compiled in federated(spec, chip):
+            print(json.dumps({"cell": cell, "program": what,
+                              **memory(compiled)}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
