@@ -26,10 +26,6 @@ The baseline carries two deliberate overrides next to the measured
     again) and `decode_scan/scan_speedup >= 2.0` (the multi-token scan
     must amortize at least 2x of the per-token dispatch cost). A drifting
     baseline can never re-bless a slowdown past its floor.
-  * "ceilings" — the dual of floors: HARD maximums, enforced verbatim,
-    for metrics where bigger is worse: e.g.
-    `obs_overhead/traced_slowdown <= 1.05` (tracing a round must never
-    cost more than 5% of it). Like floors they survive `--update`.
 
 A kernel present in the results but absent from the baseline (or vice
 versa) is SKIPPED with a note, never failed — new kernels get a baseline
@@ -58,7 +54,6 @@ DEFAULT_RESULTS = [
     os.path.join(ROOT, "benchmarks", "results", "population_scale.json"),
     os.path.join(ROOT, "benchmarks", "results", "async_rounds.json"),
     os.path.join(ROOT, "benchmarks", "results", "mesh_tp.json"),
-    os.path.join(ROOT, "benchmarks", "results", "obs_overhead.json"),
 ]
 
 
@@ -85,11 +80,9 @@ def flatten(results: Dict) -> Dict[str, float]:
 
 def check(baseline: Dict[str, float], current: Dict[str, float], *,
           threshold: float, strict: bool,
-          floors: Dict[str, float] = None,
-          ceilings: Dict[str, float] = None) -> int:
+          floors: Dict[str, float] = None) -> int:
     failures, checked, skipped = [], 0, []
     floors = floors or {}
-    ceilings = ceilings or {}
     for key, base in sorted(baseline.items()):
         if key not in current:
             skipped.append(f"{key} (no measurement this run)")
@@ -127,18 +120,6 @@ def check(baseline: Dict[str, float], current: Dict[str, float], *,
               + f"{key}: {cur:.3f}x vs HARD floor {floor:.3f}x")
         if not ok:
             failures.append(f"{key} (hard floor)")
-    # hard ceilings: absolute maximums for bigger-is-worse metrics
-    for key, ceil in sorted(ceilings.items()):
-        if key not in current:
-            skipped.append(f"{key} (ceiling set, no measurement this run)")
-            continue
-        cur = current[key]
-        ok = cur <= ceil
-        checked += 1
-        print(("ok   " if ok else "FAIL ")
-              + f"{key}: {cur:.3f}x vs HARD ceiling {ceil:.3f}x")
-        if not ok:
-            failures.append(f"{key} (hard ceiling)")
     for key in sorted(set(current) - set(baseline)):
         if key.endswith("speedup"):
             skipped.append(f"{key} (no baseline — run --update to add)")
@@ -146,7 +127,7 @@ def check(baseline: Dict[str, float], current: Dict[str, float], *,
         print(f"skip {note}")
     if failures:
         print(f"REGRESSION: {len(failures)} kernel metric(s) degraded "
-              f">{threshold:.0%} or outside a hard floor/ceiling: "
+              f">{threshold:.0%} or below a hard floor: "
               f"{failures}")
         return 1
     print(f"OK: {checked} kernel metric(s) within {threshold:.0%} "
@@ -194,13 +175,11 @@ def main(argv=None) -> int:
 
     prior_floors: Dict[str, float] = {}
     prior_pins: Dict[str, float] = {}
-    prior_ceilings: Dict[str, float] = {}
     if os.path.exists(args.baseline):
         with open(args.baseline) as f:
             prior = json.load(f)
         prior_floors = prior.get("floors", {})
         prior_pins = prior.get("pins", {})
-        prior_ceilings = prior.get("ceilings", {})
 
     if args.update:
         pins = prior_pins
@@ -211,15 +190,13 @@ def main(argv=None) -> int:
         payload = {"kernels": current,
                    "pins": pins,
                    "floors": prior_floors,
-                   "ceilings": prior_ceilings,
                    "meta": {"source": sources,
                             "threshold": args.threshold}}
         with open(args.baseline, "w") as f:
             json.dump(payload, f, indent=1, sort_keys=True)
             f.write("\n")
         print(f"wrote {args.baseline} ({len(current)} metrics, "
-              f"{pins_note} + {len(prior_floors)} floors + "
-              f"{len(prior_ceilings)} ceilings preserved)")
+              f"{pins_note} + {len(prior_floors)} floors preserved)")
         return 0
 
     if not os.path.exists(args.baseline):
@@ -230,8 +207,7 @@ def main(argv=None) -> int:
         baseline = json.load(f).get("kernels", {})
     baseline.update(prior_pins)   # pinned gate values override measured
     return check(baseline, current, threshold=args.threshold,
-                 strict=args.strict, floors=prior_floors,
-                 ceilings=prior_ceilings)
+                 strict=args.strict, floors=prior_floors)
 
 
 if __name__ == "__main__":

@@ -38,8 +38,6 @@ SUITES = {
                 "tensor-parallel body: per-device HBM ratio + round time"),
     "async": ("benchmarks.async_rounds",
               "buffered-async vs sync barrier round throughput"),
-    "obs": ("benchmarks.obs_overhead",
-            "flight-recorder overhead: traced vs untraced round"),
     "accuracy": ("benchmarks.accuracy", "Table 3 / Fig 4"),
     "prompt_length": ("benchmarks.prompt_length", "Fig 5"),
     "ablation_local_loss": ("benchmarks.ablation_local_loss", "Fig 6"),

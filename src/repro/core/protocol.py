@@ -283,124 +283,133 @@ class SFPromptTrainer:
 
         # ---- Phase 1a: local-loss self-update (vmap over clients; the
         # frozen head rides UNBATCHED through in_axes=None — no K copies)
-        if pcfg.use_local_loss and pcfg.local_epochs > 0:
-            opt_state = jax.vmap(self.opt_local.init)(trainable)
-            head_arg, head_ax = self._frozen_arg(params["head"], K)
+        with jax.named_scope("sfprompt.phase1_local"):
+            if pcfg.use_local_loss and pcfg.local_epochs > 0:
+                opt_state = jax.vmap(self.opt_local.init)(trainable)
+                head_arg, head_ax = self._frozen_arg(params["head"], K)
 
-            def one_client(hd, tr, os, d):
-                return local_epochs(
-                    model, hd, tr, self.opt_local, os, d,
-                    batch_size=pcfg.batch_size, n_epochs=pcfg.local_epochs,
-                    impl=pcfg.impl)
+                def one_client(hd, tr, os, d):
+                    return local_epochs(
+                        model, hd, tr, self.opt_local, os, d,
+                        batch_size=pcfg.batch_size, n_epochs=pcfg.local_epochs,
+                        impl=pcfg.impl)
 
-            trainable, opt_state, local_loss = jax.vmap(
-                one_client, in_axes=(head_ax, 0, 0, 0))(
-                head_arg, trainable, opt_state, client_data)
-            metrics["local_loss"] = local_loss.mean()
+                trainable, opt_state, local_loss = jax.vmap(
+                    one_client, in_axes=(head_ax, 0, 0, 0))(
+                    head_arg, trainable, opt_state, client_data)
+                metrics["local_loss"] = local_loss.mean()
 
         # ---- Phase 1b: EL2N pruning (vmap over clients)
-        if pcfg.use_pruning and model.split.prune_gamma > 0:
-            head_arg, head_ax = self._frozen_arg(params["head"], K)
+        with jax.named_scope("sfprompt.phase1b_prune"):
+            if pcfg.use_pruning and model.split.prune_gamma > 0:
+                head_arg, head_ax = self._frozen_arg(params["head"], K)
 
-            def score_one(hd, tr, d):
-                return pruning.score_client_data(
-                    model, hd, tr["tail"], tr["prompt"], d,
-                    batch_size=pcfg.batch_size, impl=pcfg.impl)
+                def score_one(hd, tr, d):
+                    return pruning.score_client_data(
+                        model, hd, tr["tail"], tr["prompt"], d,
+                        batch_size=pcfg.batch_size, impl=pcfg.impl)
 
-            scores = jax.vmap(score_one, in_axes=(head_ax, 0, 0))(
-                head_arg, trainable, client_data)
-            keep = self.phase2_keep(n_local)
-            order = jnp.argsort(-scores, axis=1)[:, :keep]
-            pruned = jax.tree.map(
-                lambda x: jnp.take_along_axis(
-                    x, order.reshape((K, keep) + (1,) * (x.ndim - 2)),
-                    axis=1) if x.ndim > 2 else
-                jnp.take_along_axis(x, order, axis=1),
-                client_data)
-            metrics["el2n_mean"] = scores.mean()
-            metrics["kept_frac"] = keep / n_local
-        else:
-            pruned, keep = client_data, n_local
+                scores = jax.vmap(score_one, in_axes=(head_ax, 0, 0))(
+                    head_arg, trainable, client_data)
+                keep = self.phase2_keep(n_local)
+                order = jnp.argsort(-scores, axis=1)[:, :keep]
+                pruned = jax.tree.map(
+                    lambda x: jnp.take_along_axis(
+                        x, order.reshape((K, keep) + (1,) * (x.ndim - 2)),
+                        axis=1) if x.ndim > 2 else
+                    jnp.take_along_axis(x, order, axis=1),
+                    client_data)
+                metrics["el2n_mean"] = scores.mean()
+                metrics["kept_frac"] = keep / n_local
+            else:
+                pruned, keep = client_data, n_local
 
         # ---- Phase 2: split training (vmap over clients; the frozen
         # {head, body} enter unbatched — phase-2 peak HBM scales with
         # K * (tail + prompt + opt state), not K * body — batched only on
         # the MoE path whose ragged_dot vmaps solely at dim 0)
-        opt_state = jax.vmap(self.opt_split.init)(trainable)
-        frozen_arg, frozen_ax = self._frozen_arg(
-            {"head": params["head"], "body": params["body"]}, K)
-        wire_keys = jax.random.split(
-            jax.random.fold_in(jax.random.PRNGKey(WIRE_SEED),
-                               state["round"]), K)
-
-        def split_one(fz, tr, os, d, wk):
-            return self._split_epochs(fz, tr, os, d, wk)
-
-        trainable, opt_state, split_loss, wire = jax.vmap(
-            split_one, in_axes=(frozen_ax, 0, 0, 0, 0))(
-            frozen_arg, trainable, opt_state, pruned, wire_keys)
-        metrics["split_loss"] = split_loss.mean()
-        transmit = participation["transmit"].astype(jnp.float32)
-        for name, per_client in wire.items():
-            # a straggler that died / hit the deadline only sent a fraction
-            # of its phase-2 traffic — scale the measured per-client bytes
-            metrics[f"wire/{name}_bytes"] = (per_client * transmit).sum()
-
-        # ---- DP-SGD on the client update: clip the round delta against
-        # the broadcast globals, add calibrated Gaussian noise — BEFORE the
-        # server (or the masked aggregator) ever sees the upload
-        if pcfg.dp_clip > 0:
-            # the reference is pure tree arithmetic (no model ops), so it
-            # rides unbatched on every architecture
-            reference = {"tail": params["tail"], "prompt": params["prompt"]}
-            dp_keys = jax.random.split(
-                jax.random.fold_in(jax.random.PRNGKey(DP_SEED),
+        with jax.named_scope("sfprompt.phase2_split"):
+            opt_state = jax.vmap(self.opt_split.init)(trainable)
+            frozen_arg, frozen_ax = self._frozen_arg(
+                {"head": params["head"], "body": params["body"]}, K)
+            wire_keys = jax.random.split(
+                jax.random.fold_in(jax.random.PRNGKey(WIRE_SEED),
                                    state["round"]), K)
 
-            def dp_one(tr, ref, dk):
-                return dp_clip_and_noise(
-                    tr, ref, dk, l2_clip=pcfg.dp_clip,
-                    noise_multiplier=pcfg.dp_noise_multiplier)
+            def split_one(fz, tr, os, d, wk):
+                return self._split_epochs(fz, tr, os, d, wk)
 
-            trainable, dp_norm = jax.vmap(dp_one, in_axes=(0, None, 0))(
-                trainable, reference, dp_keys)
-            metrics["dp/delta_norm"] = dp_norm.mean()
+            trainable, opt_state, split_loss, wire = jax.vmap(
+                split_one, in_axes=(frozen_ax, 0, 0, 0, 0))(
+                frozen_arg, trainable, opt_state, pruned, wire_keys)
+            metrics["split_loss"] = split_loss.mean()
+            transmit = participation["transmit"].astype(jnp.float32)
+            for name, per_client in wire.items():
+                # a straggler that died / hit the deadline only sent a
+                # fraction of its phase-2 traffic — scale the measured
+                # per-client bytes
+                metrics[f"wire/{name}_bytes"] = (per_client * transmit).sum()
 
-        # ---- Phase 3: participation-corrected weighted FedAvg of
-        # (tail, prompt) through the pluggable aggregator; dropped clients
-        # are excluded, a fully-lost round falls back to the pre-round
-        # globals. The secure path uploads masked uint32 ring tensors the
-        # server cannot invert (see repro/privacy/secure_agg.py).
-        aggregate = participation["aggregate"].astype(jnp.float32)
-        weights = jnp.float32(keep) * aggregate
-        fallback = {"tail": params["tail"], "prompt": params["prompt"]}
-        agg, agg_wire = self.aggregator.aggregate(trainable, weights,
-                                                  fallback, state["round"])
-        new_params = dict(params)
-        new_params["tail"] = agg["tail"]
-        new_params["prompt"] = agg["prompt"]
-        n_up = (aggregate > 0).sum()
-        param_bytes = jnp.float32(sum(
-            x.size * x.dtype.itemsize
-            for x in jax.tree.leaves(fallback)))
-        if agg_wire:
-            # metered aggregator: fp32 broadcast down to all K; the uplink
-            # is whatever the aggregator metered (masked ring uploads on
-            # the secure path) or the clear survivors-only default; key
-            # agreement / escrow reveals and the hierarchical edge->global
-            # backhaul ride their own streams
-            up = agg_wire.get("params_up", n_up * param_bytes)
-            metrics["wire/params_bytes"] = K * param_bytes + up
-            if SECURE in agg_wire:
-                metrics[f"wire/{SECURE}_bytes"] = agg_wire[SECURE]
-            if EDGE in agg_wire:
-                metrics[f"wire/{EDGE}_bytes"] = agg_wire[EDGE]
-        else:
-            # clear path: (tail, prompt) travel server->client for all K at
-            # round start and client->server only for the survivors
-            metrics["wire/params_bytes"] = (K + n_up) * param_bytes
-        metrics["cohort/active"] = n_up
-        metrics["cohort/transmit_sum"] = transmit.sum()
+        # the DP clip below rides inside phase 3's scope: it is the last
+        # step before the upload
+        with jax.named_scope("sfprompt.phase3_aggregate"):
+            # ---- DP-SGD on the client update: clip the round delta
+            # against the broadcast globals, add calibrated Gaussian noise
+            # — BEFORE the server (or the masked aggregator) ever sees the
+            # upload
+            if pcfg.dp_clip > 0:
+                # the reference is pure tree arithmetic (no model ops), so it
+                # rides unbatched on every architecture
+                reference = {"tail": params["tail"],
+                             "prompt": params["prompt"]}
+                dp_keys = jax.random.split(
+                    jax.random.fold_in(jax.random.PRNGKey(DP_SEED),
+                                       state["round"]), K)
+
+                def dp_one(tr, ref, dk):
+                    return dp_clip_and_noise(
+                        tr, ref, dk, l2_clip=pcfg.dp_clip,
+                        noise_multiplier=pcfg.dp_noise_multiplier)
+
+                trainable, dp_norm = jax.vmap(dp_one, in_axes=(0, None, 0))(
+                    trainable, reference, dp_keys)
+                metrics["dp/delta_norm"] = dp_norm.mean()
+
+            # ---- Phase 3: participation-corrected weighted FedAvg of
+            # (tail, prompt) through the pluggable aggregator; dropped clients
+            # are excluded, a fully-lost round falls back to the pre-round
+            # globals. The secure path uploads masked uint32 ring tensors the
+            # server cannot invert (see repro/privacy/secure_agg.py).
+            aggregate = participation["aggregate"].astype(jnp.float32)
+            weights = jnp.float32(keep) * aggregate
+            fallback = {"tail": params["tail"], "prompt": params["prompt"]}
+            agg, agg_wire = self.aggregator.aggregate(trainable, weights,
+                                                      fallback, state["round"])
+            new_params = dict(params)
+            new_params["tail"] = agg["tail"]
+            new_params["prompt"] = agg["prompt"]
+            n_up = (aggregate > 0).sum()
+            param_bytes = jnp.float32(sum(
+                x.size * x.dtype.itemsize
+                for x in jax.tree.leaves(fallback)))
+            if agg_wire:
+                # metered aggregator: fp32 broadcast down to all K; the uplink
+                # is whatever the aggregator metered (masked ring uploads on
+                # the secure path) or the clear survivors-only default; key
+                # agreement / escrow reveals and the hierarchical edge->global
+                # backhaul ride their own streams
+                up = agg_wire.get("params_up", n_up * param_bytes)
+                metrics["wire/params_bytes"] = K * param_bytes + up
+                if SECURE in agg_wire:
+                    metrics[f"wire/{SECURE}_bytes"] = agg_wire[SECURE]
+                if EDGE in agg_wire:
+                    metrics[f"wire/{EDGE}_bytes"] = agg_wire[EDGE]
+            else:
+                # clear path: (tail, prompt) travel server->client for all K at
+                # round start and client->server only for the survivors
+                metrics["wire/params_bytes"] = (K + n_up) * param_bytes
+            metrics["cohort/active"] = n_up
+            metrics["cohort/transmit_sum"] = transmit.sum()
 
         extras = ({"trainable": trainable}
                   if pcfg.return_client_trainable else {})
@@ -419,17 +428,19 @@ class SFPromptTrainer:
             ones = jnp.ones((K,), jnp.float32)
             participation = {"transmit": ones, "aggregate": ones}
         tracer = self.tracer
-        with tracer.span("round") as sp:
-            round_jit = self._get_round_jit(state, client_data,
-                                            participation, init_tails)
-            if tracer.enabled:
-                tracer.event("round.dispatch", level=2, cohort=K,
-                             personalized_tails=init_tails is not None)
-            with tracer.annotate("sfprompt.round"):
+        with tracer.span("sfprompt.round") as sp:
+            with tracer.span("sfprompt.dispatch"):
+                round_jit = self._get_round_jit(state, client_data,
+                                                participation, init_tails)
+                if tracer.enabled:
+                    tracer.event("round.dispatch", level=2, cohort=K,
+                                 personalized_tails=init_tails is not None)
                 state, metrics, extras = round_jit(state, client_data,
                                                    participation, init_tails)
-            self.last_client_trainable = extras.get("trainable")
-            metrics = {k: float(v) for k, v in metrics.items()}
+                self.last_client_trainable = extras.get("trainable")
+            with tracer.span("sfprompt.sync"):
+                # the host waits here for the device to finish the round
+                metrics = {k: float(v) for k, v in metrics.items()}
             if self.accountant is not None:
                 # one Gaussian release of each sampled client's update per
                 # round — the ledger tracks the per-client (local-model)

@@ -28,13 +28,14 @@ from repro.fed.population import Population
 from repro.fed.sampler import ClientSampler
 from repro.fed.scheduler import (FullParticipationScheduler, RoundPlan,
                                  RoundScheduler)
+from repro.obs.trace import NOOP
 
 
 class FederatedEngine:
     def __init__(self, trainer, population: Population,
                  sampler: ClientSampler,
                  scheduler: Optional[RoundScheduler] = None, *,
-                 personalize_tails: bool = False):
+                 personalize_tails: bool = False, tracer=None):
         if sampler.n_clients != population.n_clients:
             raise ValueError(
                 f"sampler over {sampler.n_clients} clients but population "
@@ -58,6 +59,8 @@ class FederatedEngine:
                 "ProtocolConfig(return_client_trainable=True) so per-client "
                 "tails survive the round")
         self.personalize_tails = personalize_tails
+        # flight recorder (repro.obs): the default NOOP records nothing
+        self.tracer = tracer if tracer is not None else NOOP
         self.round_idx = 0
         self.state: Optional[Dict[str, Any]] = None
         self.cohort_history: list = []   # per-round sampled ids (this run)
@@ -74,15 +77,32 @@ class FederatedEngine:
     # --------------------------------------------------------------- round
     def run_round(self) -> Tuple[RoundPlan, Dict[str, float]]:
         """Sample -> gather -> schedule -> train -> write back. Returns the
-        straggler plan and the trainer metrics for the round."""
+        straggler plan and the trainer metrics for the round.
+
+        Each stage is a span of the engine's tracer: `fed.round` over
+        `fed.sample`, `fed.gather`, `fed.put` (the cohort's copy to the
+        device), the trainer's `sfprompt.round` and `fed.writeback`."""
         if self.state is None:
             raise RuntimeError("call init(key) or restore(ckpt_dir) first")
+        tracer = self.tracer
         r = self.round_idx
-        cohort = self.sampler.sample(r)
-        plan = self.scheduler.plan(cohort, r)
-        data = {k: jnp.asarray(v) for k, v in
-                self.population.gather(cohort).items()}
+        with tracer.span("fed.round", round=r) as round_span:
+            with tracer.span("fed.sample"):
+                cohort = self.sampler.sample(r)
+                plan = self.scheduler.plan(cohort, r)
+            round_span.set(cohort=len(cohort))
+            with tracer.span("fed.gather") as sp:
+                host = self.population.gather(cohort)
+                nbytes = int(sum(v.nbytes for v in host.values()))
+                sp.set(bytes=nbytes)
+            with tracer.span("fed.put", bytes=nbytes):
+                data = {k: jnp.asarray(v) for k, v in host.items()}
+            metrics = self._train(cohort, plan, data)
+            with tracer.span("fed.writeback"):
+                self._write_back(cohort, plan, r, metrics)
+        return plan, metrics
 
+    def _train(self, cohort, plan: RoundPlan, data) -> Dict[str, float]:
         if getattr(self.trainer, "supports_partial", False):
             part = plan.participation()
             # paper Eq. 3: FedAvg weighted by TRUE per-client sample counts
@@ -109,7 +129,10 @@ class FederatedEngine:
             # baselines (FL / SFL) predate partial participation: they run
             # the cohort synchronously and ignore the straggler plan
             self.state, metrics = self.trainer.round(self.state, data)
+        return metrics
 
+    def _write_back(self, cohort, plan: RoundPlan, r: int,
+                    metrics: Dict[str, float]) -> None:
         if self.personalize_tails:
             per_client = getattr(self.trainer, "last_client_trainable", None)
             if per_client is not None:
@@ -128,7 +151,6 @@ class FederatedEngine:
         metrics["cohort/late"] = float(plan.late.sum())
         self.cohort_history.append(np.asarray(cohort))
         self.round_idx = r + 1
-        return plan, metrics
 
     # ------------------------------------------------------------- resume
     def _trainer_fingerprint(self) -> np.int64:

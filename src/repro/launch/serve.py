@@ -166,9 +166,6 @@ def main(argv=None):
     ap.add_argument("--metrics-every", type=int, default=0,
                     help="print a metrics-registry snapshot every N engine "
                          "steps (0 = only at the end when tracing is on)")
-    ap.add_argument("--trace-profiler", action="store_true",
-                    help="wrap traced device dispatches in jax.profiler "
-                         "TraceAnnotations (visible in a profiler capture)")
     args = ap.parse_args(argv)
 
     enable_compile_cache()
@@ -184,7 +181,7 @@ def main(argv=None):
     trace_level = args.trace_level
     if args.trace_out and trace_level == "off":
         trace_level = "round"
-    tracer = make_tracer(trace_level, profiler=args.trace_profiler)
+    tracer = make_tracer(trace_level)
 
     bank = personalized_bank(model, params, args.tenants)
     # the bank holds the tails: drop the init's own tail so one copy stays

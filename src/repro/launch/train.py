@@ -183,7 +183,7 @@ def build_scheduler(args, population, cfg, split):
         round_flops_per_client=sfprompt_compute(ci))
 
 
-def build_engine(args, trainer, population, cfg, split):
+def build_engine(args, trainer, population, cfg, split, tracer=None):
     sampler = ClientSampler(
         population.n_clients, args.k, kind=args.sampler, seed=args.seed,
         weights=(population.sizes.astype(float)
@@ -205,7 +205,8 @@ def build_engine(args, trainer, population, cfg, split):
     if args.dropout_rate > 0 or args.straggle:
         scheduler = build_scheduler(args, population, cfg, split)
     return FederatedEngine(trainer, population, sampler, scheduler,
-                           personalize_tails=args.personalize_tails)
+                           personalize_tails=args.personalize_tails,
+                           tracer=tracer)
 
 
 def main(argv=None):
@@ -311,9 +312,6 @@ def main(argv=None):
     ap.add_argument("--metrics-every", type=int, default=0,
                     help="print a metrics-registry snapshot every N rounds "
                          "(0 = only at the end when tracing is on)")
-    ap.add_argument("--trace-profiler", action="store_true",
-                    help="wrap traced device dispatches in jax.profiler "
-                         "TraceAnnotations (visible in a profiler capture)")
     args = ap.parse_args(argv)
     if args.personalize_tails and not args.method.startswith("sfprompt"):
         ap.error("--personalize-tails needs an sfprompt method")
@@ -376,10 +374,10 @@ def main(argv=None):
     trace_level = args.trace_level
     if args.trace_out and trace_level == "off":
         trace_level = "round"
-    tracer = make_tracer(trace_level, profiler=args.trace_profiler)
+    tracer = make_tracer(trace_level)
 
     trainer = build_trainer(args, model, build_mesh(args), tracer=tracer)
-    engine = build_engine(args, trainer, population, cfg, split)
+    engine = build_engine(args, trainer, population, cfg, split, tracer)
     ckpt_dir = os.path.join(args.out, "ckpt")
 
     registry = MetricsRegistry()

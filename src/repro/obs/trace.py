@@ -27,6 +27,13 @@ runtime's records instead carry the engine's SIMULATED clock (`t_sim` /
 `dur_sim`, seconds) via `event_at`/`span_at` — the Chrome-trace exporter
 lays them out as a separate process track.
 
+Every host span a live tracer records is also a
+`jax.profiler.TraceAnnotation` of the same name for the same extent, so
+under an active profiler session it lands on the `/host:CPU` plane on the
+device trace's clock; with no session the annotation costs next to
+nothing. jax is imported only when a live `Tracer` is built, so
+`repro.obs` imports without it.
+
 Levels: ``off`` (0) records nothing, ``round`` (1) the lifecycle
 (rounds, flushes, admissions, retirements, meter folds), ``step`` (2)
 adds per-dispatch detail (decode steps, page-pool churn, buffer traffic).
@@ -91,17 +98,14 @@ class NoopTracer:
     def records(self) -> Tuple:
         return ()
 
-    def annotate(self, name: str):
-        from contextlib import nullcontext
-        return nullcontext()
-
 
 NOOP = NoopTracer()
 
 
 class _Span:
-    """Open span handle; records one complete record at exit."""
-    __slots__ = ("_tracer", "name", "level", "attrs", "_t0")
+    """Open span handle; records one complete record at exit and holds
+    the profiler annotation of the same name open meanwhile."""
+    __slots__ = ("_tracer", "name", "level", "attrs", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, level: int,
                  attrs: Dict[str, Any]):
@@ -111,6 +115,8 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
+        self._annotation = self._tracer._annotate(self.name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         self._tracer._depth += 1
         return self
@@ -127,6 +133,7 @@ class _Span:
         tr._push({"seq": next(tr._seq), "kind": "span", "name": self.name,
                   "depth": tr._depth, "t_ns": self._t0,
                   "dur_ns": t1 - self._t0, "attrs": self.attrs})
+        self._annotation.__exit__(*exc)
         return False
 
 
@@ -140,11 +147,12 @@ class Tracer:
     """
 
     def __init__(self, level: int = LEVEL_ROUND, *,
-                 capacity: int = 1 << 16, profiler: bool = False):
+                 capacity: int = 1 << 16):
+        from jax.profiler import TraceAnnotation
         if isinstance(level, str):
             level = LEVELS[level]
         self.level = int(level)
-        self.profiler = profiler
+        self._annotate = TraceAnnotation
         self._buf: deque = deque(maxlen=capacity)
         self._seq = itertools.count()
         self._depth = 0
@@ -198,16 +206,6 @@ class Tracer:
                     "dur_sim": float(t1_sim) - float(t0_sim),
                     "lane": int(lane), "attrs": attrs})
 
-    def annotate(self, name: str):
-        """Opt-in `jax.profiler.TraceAnnotation` around a jitted step —
-        shows up in XLA profiler timelines; a no-op nullcontext unless
-        the tracer was built with profiler=True."""
-        if not self.profiler:
-            from contextlib import nullcontext
-            return nullcontext()
-        import jax.profiler
-        return jax.profiler.TraceAnnotation(name)
-
     # ------------------------------------------------------------- reading
     def records(self) -> List[Dict[str, Any]]:
         return list(self._buf)
@@ -218,13 +216,12 @@ class Tracer:
         return out
 
 
-def make_tracer(level: Any = "off", *, capacity: int = 1 << 16,
-                profiler: bool = False):
+def make_tracer(level: Any = "off", *, capacity: int = 1 << 16):
     """`NOOP` for "off"/0/None, a live `Tracer` otherwise — the one
     constructor launchers need."""
     if level in (None, "off", LEVEL_OFF, False):
         return NOOP
-    return Tracer(level, capacity=capacity, profiler=profiler)
+    return Tracer(level, capacity=capacity)
 
 
 # ----------------------------------------------------------------- helpers

@@ -273,9 +273,8 @@ class ServeEngine:
         with self.tracer.span("serve.prefill", rid=req.rid,
                               tenant=req.tenant, slot=slot,
                               prompt_len=len(req.tokens)):
-            with self.tracer.annotate("serve.prefill"):
-                tok, logits, slot_cache, wb = self._prefill(
-                    self.shared, tail, prompt, batch, self._blank)
+            tok, logits, slot_cache, wb = self._prefill(
+                self.shared, tail, prompt, batch, self._blank)
             self.cache = self._write_slot(self.cache, slot_cache,
                                           jnp.int32(slot))
         self._absorb_wire(wb)
@@ -389,8 +388,7 @@ class ServeEngine:
         with self.tracer.span("serve.decode", level=2, step=self.step_idx,
                               n_tokens=n_eff,
                               active=int((remaining > 0).sum())):
-            with self.tracer.annotate("serve.decode"):
-                toks, logits, wb = self._dispatch_decode(remaining, n_eff)
+            toks, logits, wb = self._dispatch_decode(remaining, n_eff)
         self._absorb_wire(wb)
         self.decode_steps += n_eff
         for t in range(n_eff):

@@ -28,11 +28,14 @@ import pytest
 
 from repro.configs import get_config
 from repro.core import ProtocolConfig, SFPromptTrainer, SplitConfig, SplitModel
+from repro.core.aggregation import get_aggregator
 from repro.data import (DATASETS, iid_partition, select_clients,
                         stack_clients, synthetic_image_dataset)
+from repro.fed import ClientSampler, FederatedEngine, Population
 from repro.launch.mesh import report_sharding_fallbacks
 from repro.obs import (LEVELS, MetricsRegistry, NOOP, Tracer, chrome_trace,
-                       make_tracer, prometheus_text, strip_times, sum_stream)
+                       make_tracer, prometheus_text, span_tree, strip_times,
+                       sum_stream)
 from repro.obs.export import meter_final_record, write_jsonl
 from repro.runtime import WireSpec
 from repro.runtime.meter import TrafficMeter, WALL_STREAMS
@@ -58,10 +61,14 @@ def proto_setup():
     return model, clients
 
 
-def _run_rounds(model, clients, tracer=None, n=2, k=2):
+def _trainer(model, tracer=None, k=2, aggregator=None):
     pcfg = ProtocolConfig(clients_per_round=k, local_epochs=1, batch_size=8,
                           lr_local=0.05, lr_split=0.05, momentum=0.0)
-    tr = SFPromptTrainer(model, pcfg, tracer=tracer)
+    return SFPromptTrainer(model, pcfg, aggregator, tracer=tracer)
+
+
+def _run_rounds(model, clients, tracer=None, n=2, k=2):
+    tr = _trainer(model, tracer, k)
     state = tr.init(KEY)
     for r in range(n):
         idx = select_clients(len(clients), k, seed=0, round_idx=r)
@@ -69,6 +76,23 @@ def _run_rounds(model, clients, tracer=None, n=2, k=2):
                  stack_clients(clients, idx).items()}
         state, _ = tr.round(state, batch)
     return tr, state
+
+
+def _engine(model, clients, tracer=None, k=2):
+    """A FederatedEngine over the fixture's clients; the trainer and the
+    engine share `tracer`, as the train launcher wires them."""
+    eng = FederatedEngine(_trainer(model, tracer, k),
+                          Population.from_client_list(clients),
+                          ClientSampler(len(clients), k, seed=0),
+                          tracer=tracer)
+    eng.init(KEY)
+    return eng
+
+
+def _run_engine(model, clients, tracer=None, n=2, k=2):
+    eng = _engine(model, clients, tracer, k)
+    metrics = [eng.run_round()[1] for _ in range(n)]
+    return eng.trainer, eng.state, metrics
 
 
 @pytest.fixture(scope="module")
@@ -119,12 +143,22 @@ def _trees_equal(a, b):
 
 
 # ----------------------------------------- tracing off == never happened
-def test_round_bit_identical_with_and_without_tracing(proto_setup):
+@pytest.mark.parametrize("entry", ["trainer", "engine"])
+def test_round_bit_identical_with_and_without_tracing(proto_setup, entry):
     """Headline criterion half 1: a traced round computes the SAME
-    params and meters the SAME bytes as an untraced one."""
+    params, metrics and metered bytes as an untraced one, whether the
+    trainer is driven directly or by the FederatedEngine (whose stage
+    spans wrap sampling, the gather and the copy to the device)."""
     model, clients = proto_setup
-    tr_off, st_off = _run_rounds(model, clients, tracer=None)
-    tr_on, st_on = _run_rounds(model, clients, tracer=Tracer("step"))
+    if entry == "trainer":
+        (tr_off, st_off), (tr_on, st_on) = (
+            _run_rounds(model, clients, tracer=t)
+            for t in (None, Tracer("step")))
+    else:
+        (tr_off, st_off, m_off), (tr_on, st_on, m_on) = (
+            _run_engine(model, clients, tracer=t)
+            for t in (None, Tracer("step")))
+        assert m_off == m_on                  # every metric, exact floats
     assert tr_off.tracer is NOOP          # default wiring
     assert _trees_equal(st_off["params"], st_on["params"])
     assert tr_off.meter.totals == tr_on.meter.totals   # exact floats
@@ -160,7 +194,7 @@ def test_round_trace_bytes_sum_exactly_to_meter(proto_setup):
     for stream, total in tr.meter.totals.items():
         assert sum_stream(recs, "meter.absorb", stream) == total
     # the round spans carry the same folded floats as attributes
-    spans = [r for r in recs if r["name"] == "round"]
+    spans = [r for r in recs if r["name"] == "sfprompt.round"]
     assert len(spans) == 3
     for stream in ("head_body", "body_tail", "params"):
         assert sum(s["attrs"][stream] for s in spans) == \
@@ -173,6 +207,66 @@ def test_serve_trace_bytes_sum_exactly_to_meter(serve_setup):
     recs = eng.tracer.records()
     for stream, total in eng.meter.totals.items():
         assert sum_stream(recs, "meter.absorb", stream) == total
+
+
+# ------------------------------------------------- spans along the round
+ROUND_TREE = [(1, "fed.sample"), (1, "fed.gather"), (1, "fed.put"),
+              (2, "sfprompt.dispatch"), (2, "sfprompt.sync"),
+              (1, "sfprompt.round"), (1, "fed.writeback"), (0, "fed.round")]
+
+
+def test_engine_round_span_tree(proto_setup):
+    """One traced round is exactly the stage spans, nested as listed
+    (records push at exit, so children precede their parent), and the
+    gather and the copy to the device carry the cohort's bytes."""
+    model, clients = proto_setup
+    eng = _engine(model, clients, Tracer("round"))
+    eng.run_round()
+    recs = eng.tracer.records()
+    assert [(d, n) for d, n, _ in span_tree(recs)] == ROUND_TREE
+    spans = {r["name"]: r for r in recs if r["kind"] == "span"}
+    cohort = eng.cohort_history[0]
+    nbytes = sum(v.nbytes for v in eng.population.gather(cohort).values())
+    assert spans["fed.gather"]["attrs"] == {"bytes": nbytes}
+    assert spans["fed.put"]["attrs"] == {"bytes": nbytes}
+    assert spans["fed.round"]["attrs"] == {"round": 0, "cohort": 2}
+
+
+def test_spans_are_profiler_annotations(proto_setup, tmp_path):
+    """Under a profiler session the live tracer's spans land on the
+    /host:CPU plane by name, on the profiler's clock."""
+    model, clients = proto_setup
+    eng = _engine(model, clients, Tracer("round"))
+    eng.run_round()                       # compile outside the capture
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run_round()
+        jax.block_until_ready(eng.params)
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    plane = jax.profiler.ProfileData.from_file(str(path)) \
+        .find_plane_with_name("/host:CPU")
+    names = {e.name for line in plane.lines for e in line.events}
+    assert {n for _, n in ROUND_TREE} <= names
+
+
+@pytest.mark.parametrize("secure", [False, True])
+def test_round_program_names_its_phases(proto_setup, secure):
+    """The lowered round carries the four phase scopes in its op
+    metadata, on the clear and the secure aggregation path."""
+    model, clients = proto_setup
+    agg = get_aggregator(secure=True, impl="ref") if secure else None
+    tr = _trainer(model, aggregator=agg)
+    batch = {kk: jnp.asarray(v) for kk, v in
+             stack_clients(clients, [0, 1]).items()}
+    ones = jnp.ones((2,), jnp.float32)
+    text = tr._round_jit.lower(
+        tr.init(KEY), batch, {"transmit": ones, "aggregate": ones},
+        None).as_text(debug_info=True)
+    for scope in ("sfprompt.phase1_local", "sfprompt.phase1b_prune",
+                  "sfprompt.phase2_split", "sfprompt.phase3_aggregate"):
+        assert f"/{scope}/" in text, scope
 
 
 # ------------------------------------------------------------ determinism
