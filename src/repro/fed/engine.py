@@ -2,7 +2,8 @@
 
 One object owns the full loop the launcher used to hand-roll:
 
-    sample cohort (ClientSampler)  ->  gather data (Population)
+    sample cohort (ClientSampler)  ->  gather data (Population, on the
+    device where the dataset fits there)
     ->  simulate stragglers (RoundScheduler)  ->  train the cohort
     (SFPromptTrainer._round, vmapped K-axis intact)  ->  write back
     per-client state  ->  checkpoint.
@@ -80,8 +81,10 @@ class FederatedEngine:
         straggler plan and the trainer metrics for the round.
 
         Each stage is a span of the engine's tracer: `fed.round` over
-        `fed.sample`, `fed.gather`, `fed.put` (the cohort's copy to the
-        device), the trainer's `sfprompt.round` and `fed.writeback`."""
+        `fed.sample`, `fed.gather` (attr `where`: "device" when the
+        population's data is resident there, else "host"), `fed.put` (the
+        cohort's copy to the device, a no-op after a device gather), the
+        trainer's `sfprompt.round` and `fed.writeback`."""
         if self.state is None:
             raise RuntimeError("call init(key) or restore(ckpt_dir) first")
         tracer = self.tracer
@@ -91,12 +94,13 @@ class FederatedEngine:
                 cohort = self.sampler.sample(r)
                 plan = self.scheduler.plan(cohort, r)
             round_span.set(cohort=len(cohort))
-            with tracer.span("fed.gather") as sp:
-                host = self.population.gather(cohort)
-                nbytes = int(sum(v.nbytes for v in host.values()))
+            with tracer.span("fed.gather",
+                             where=self.population.where) as sp:
+                gathered = self.population.gather(cohort)
+                nbytes = int(sum(v.nbytes for v in gathered.values()))
                 sp.set(bytes=nbytes)
             with tracer.span("fed.put", bytes=nbytes):
-                data = {k: jnp.asarray(v) for k, v in host.items()}
+                data = {k: jnp.asarray(v) for k, v in gathered.items()}
             metrics = self._train(cohort, plan, data)
             with tracer.span("fed.writeback"):
                 self._write_back(cohort, plan, r, metrics)

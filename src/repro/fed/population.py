@@ -8,6 +8,16 @@ only the sampled cohort via `gather()`. Memory is O(dataset + N) instead of
 O(N * dataset); the cohort tensor stays exactly the (K, n_local, ...) layout
 `SFPromptTrainer._round` vmaps over.
 
+Where the dataset lives: on the default device, placed once at
+construction, whenever its bytes fit in `1 / DEVICE_SHARE` of the device's
+`memory_stats()["bytes_limit"]` (always on a backend that reports no
+limit, such as the CPU). `gather()` then takes the cohort's rows with one
+jitted gather per key on the device, and the chip never waits on a host
+copy. A dataset over the share stays in host memory and `gather()`
+fancy-indexes it in numpy, so population-scale data cannot crowd the
+round's own buffers out of the device. Either way each gather returns
+fresh buffers (the trainer may donate them), and `where` names the path.
+
 Per-client PERSISTENT state rides along:
   * `sizes`        — true pre-padding sample counts (FedAvg / weighted
                      sampling weights),
@@ -21,12 +31,31 @@ Per-client PERSISTENT state rides along:
 from __future__ import annotations
 
 import zlib
-from typing import Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
 
 from repro.data.federated import dirichlet_indices, iid_indices
+
+# Largest share of the device's memory the resident dataset may take, as a
+# divisor: a K=3 vit-base round at published widths already holds ~13.2 of
+# the v5e's 16.9 GB in temporaries, leaving ~3.7 GB, and an eighth (2.1 GB)
+# keeps the dataset well inside that.
+DEVICE_SHARE = 8
+
+
+def device_bytes_limit() -> Optional[int]:
+    """The default device's memory in bytes, or None where the backend
+    reports none (the CPU)."""
+    stats = jax.devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
+@jax.jit
+def _take_rows(data, rows):
+    """(K, n_local, ...) per key: each client's rows, gathered on device."""
+    return {k: v[rows] for k, v in data.items()}
 
 
 class Population:
@@ -37,7 +66,16 @@ class Population:
         if len(lens) != 1:
             raise ValueError(f"client index arrays must share one length "
                              f"for stacking; got {sorted(lens)}")
-        self.data = data
+        # the partition's identity is the data's HOST layout: placing int64
+        # labels on a device without x64 narrows them to int32
+        self._layout = {k: f"{k}:{v.shape}:{v.dtype}"
+                        for k, v in data.items()}
+        nbytes = sum(v.nbytes for v in data.values())
+        limit = device_bytes_limit()
+        self.where = ("device" if limit is None
+                      or nbytes <= limit // DEVICE_SHARE else "host")
+        self.data = (jax.device_put(data) if self.where == "device"
+                     else data)
         self.client_indices = [np.asarray(i, dtype=np.int64)
                                for i in client_indices]
         self.n_clients = len(client_indices)
@@ -82,10 +120,14 @@ class Population:
         return cls(data, idx, np.asarray(sizes))
 
     # ------------------------------------------------------------- cohort
-    def gather(self, cohort: Sequence[int]) -> Dict[str, np.ndarray]:
-        """Materialize the sampled cohort: (K, n_local, ...) per key."""
+    def gather(self, cohort: Sequence[int]) -> Dict[str, Any]:
+        """Materialize the sampled cohort: (K, n_local, ...) per key, as
+        fresh device arrays (or numpy arrays where the data stays on the
+        host; see `where`)."""
         rows = np.stack([self.client_indices[int(c)] for c in cohort])
-        return {k: v[rows] for k, v in self.data.items()}
+        if self.where == "host":
+            return {k: v[rows] for k, v in self.data.items()}
+        return _take_rows(self.data, rows.astype(np.int32))
 
     def cohort_sizes(self, cohort: Sequence[int]) -> np.ndarray:
         return self.sizes[np.asarray(cohort, dtype=np.int64)]
@@ -126,10 +168,8 @@ class Population:
         for idx in self.client_indices:
             idx_crc = zlib.crc32(idx.tobytes(), idx_crc)
         shape_crc = 0
-        for k in sorted(self.data):
-            v = self.data[k]
-            shape_crc = zlib.crc32(
-                f"{k}:{v.shape}:{v.dtype}".encode(), shape_crc)
+        for k in sorted(self._layout):
+            shape_crc = zlib.crc32(self._layout[k].encode(), shape_crc)
         return {"n_clients": np.int64(self.n_clients),
                 "n_local": np.int64(self.n_local),
                 "sizes_crc": np.int64(zlib.crc32(self.sizes.tobytes())),

@@ -19,6 +19,7 @@ from repro.core.aggregation import (broadcast_to_clients, fedavg_partial,
                                     get_aggregator, hierarchical_fedavg)
 from repro.core.comm import (hierarchical_edge_breakdown,
                              hierarchical_secure_agg_breakdown)
+import repro.fed.population as population_mod
 from repro.data import (DATASETS, synthetic_image_dataset,
                         synthetic_lm_dataset)
 from repro.fed import (ClientSampler, EdgeTopology, FederatedEngine,
@@ -358,6 +359,46 @@ def test_sharded_jit_cache_reused_across_rounds(setup):
     state, _ = tr.round(state, data)
     assert len(tr._mesh_jit_cache) == 1
     assert int(state["round"]) == 2
+
+
+@needs_mesh
+def test_device_gathered_cohort_enters_donating_sharded_round(
+        setup, monkeypatch):
+    """A cohort gathered on the device enters the sharded round jit that
+    donates it, as the launcher builds it over a mesh: two engine rounds
+    equal those fed from the host gather, bit for bit, and the resident
+    dataset survives the donations."""
+    cfg, split = setup
+    n_clients, k = 16, 8
+    data = synthetic_image_dataset(DATASETS["cifar10-syn"],
+                                   n_clients * N_LOCAL, seed=0, image_hw=32)
+    mesh = make_host_mesh()
+
+    def run():
+        pop = Population.from_partition(data, n_clients, scheme="iid",
+                                        seed=0)
+        tr = SFPromptTrainer(
+            SplitModel(cfg, split),
+            ProtocolConfig(clients_per_round=k, local_epochs=1,
+                           batch_size=BATCH, momentum=0.0),
+            mesh=mesh, donate_cohort=True)
+        eng = FederatedEngine(tr, pop, ClientSampler(n_clients, k, seed=5))
+        eng.init(KEY)
+        metrics = [eng.run_round()[1] for _ in range(2)]
+        return eng, metrics
+
+    dev, m_dev = run()
+    monkeypatch.setattr(population_mod, "device_bytes_limit", lambda: 8)
+    host, m_host = run()
+    assert (dev.population.where, host.population.where) == \
+        ("device", "host")
+    assert m_dev == m_host
+    for a, b in zip(jax.tree.leaves(dev.params),
+                    jax.tree.leaves(host.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for name, v in data.items():
+        np.testing.assert_array_equal(np.asarray(dev.population.data[name]),
+                                      v)
 
 
 # ------------------------------------------------------------------ resume

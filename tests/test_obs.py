@@ -217,8 +217,9 @@ ROUND_TREE = [(1, "fed.sample"), (1, "fed.gather"), (1, "fed.put"),
 
 def test_engine_round_span_tree(proto_setup):
     """One traced round is exactly the stage spans, nested as listed
-    (records push at exit, so children precede their parent), and the
-    gather and the copy to the device carry the cohort's bytes."""
+    (records push at exit, so children precede their parent), the gather
+    and the copy to the device carry the cohort's bytes, and the gather
+    names the path it took: the device, where the data lives."""
     model, clients = proto_setup
     eng = _engine(model, clients, Tracer("round"))
     eng.run_round()
@@ -227,7 +228,8 @@ def test_engine_round_span_tree(proto_setup):
     spans = {r["name"]: r for r in recs if r["kind"] == "span"}
     cohort = eng.cohort_history[0]
     nbytes = sum(v.nbytes for v in eng.population.gather(cohort).values())
-    assert spans["fed.gather"]["attrs"] == {"bytes": nbytes}
+    assert spans["fed.gather"]["attrs"] == {"bytes": nbytes,
+                                            "where": "device"}
     assert spans["fed.put"]["attrs"] == {"bytes": nbytes}
     assert spans["fed.round"]["attrs"] == {"round": 0, "cohort": 2}
 

@@ -1,6 +1,7 @@
 """Population-scale federated rounds: sampler determinism, straggler
 scheduling, partial-participation FedAvg, partial-cohort wire metering vs
-the analytical model, and byte-identical kill-and-restart resume."""
+the analytical model, byte-identical kill-and-restart resume, and the
+device-resident dataset's gather against the host gather."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +12,7 @@ from repro.core import ProtocolConfig, SFPromptTrainer, SplitConfig, SplitModel
 from repro.core.aggregation import fedavg_partial
 from repro.core.comm import (crosscheck, measured_cost_inputs,
                              sfprompt_comm_breakdown_partial)
+import repro.fed.population as population_mod
 from repro.data import DATASETS, synthetic_image_dataset
 from repro.fed import (ClientSampler, FederatedEngine, Population,
                        RoundScheduler, StragglerConfig)
@@ -220,6 +222,130 @@ def test_population_participation_state(setup):
                                       scheme="iid", seed=1)
     with pytest.raises(ValueError, match="population mismatch"):
         other.load_state_dict(state)
+
+
+def no_device_room(monkeypatch):
+    """From here on the device reports too little memory for any dataset,
+    so populations built after this keep their data on the host."""
+    monkeypatch.setattr(population_mod, "device_bytes_limit", lambda: 8)
+
+
+@pytest.mark.parametrize("scheme", ["iid", "dirichlet"])
+def test_device_gather_equals_host_gather(setup, monkeypatch, scheme):
+    """The resident dataset's gather returns what the host gather does,
+    value for value and in dtype, for clients whose rows repeat too."""
+    _, _, data, _ = setup
+
+    def build():
+        return Population.from_partition(data, N_CLIENTS, scheme=scheme,
+                                          alpha=0.1, seed=0)
+    dev = build()
+    no_device_room(monkeypatch)
+    host = build()
+    assert (dev.where, host.where) == ("device", "host")
+    short = np.flatnonzero(dev.sizes < dev.n_local)[:2].tolist()
+    if scheme == "dirichlet":
+        # padded clients resample their own rows
+        assert short and len(np.unique(dev.client_indices[short[0]])) \
+            < dev.n_local
+    cohort = [0, 500, 999] + short
+    got, want = dev.gather(cohort), host.gather(cohort)
+    assert set(got) == set(want)
+    for k in want:
+        assert isinstance(got[k], jax.Array)
+        assert isinstance(want[k], np.ndarray)
+        assert got[k].dtype == want[k].dtype
+        np.testing.assert_array_equal(np.asarray(got[k]), want[k])
+    assert sum(v.nbytes for v in got.values()) == \
+        sum(v.nbytes for v in want.values())
+    assert dev.fingerprint() == host.fingerprint()
+
+
+def test_dataset_over_the_share_stays_on_host(setup, monkeypatch):
+    """The data goes to the device only within 1/DEVICE_SHARE of its
+    memory; past that the host gather stays and returns numpy."""
+    _, _, data, pop = setup
+    nbytes = sum(v.nbytes for v in data.values())
+    fits = nbytes * population_mod.DEVICE_SHARE
+    for limit, where in [(fits, "device"), (fits - 1, "host")]:
+        monkeypatch.setattr(population_mod, "device_bytes_limit",
+                            lambda limit=limit: limit)
+        p = Population(data, pop.client_indices, pop.sizes)
+        assert p.where == where
+    assert p.data is data
+    out = p.gather([3, 7])
+    assert all(type(v) is np.ndarray for v in out.values())
+    np.testing.assert_array_equal(out["labels"][1],
+                                  data["labels"][pop.client_indices[7]])
+
+
+def test_fingerprint_reads_the_host_layout(monkeypatch):
+    """int64 labels narrow to int32 on a device without x64; the
+    partition's fingerprint still reads the data as given, so checkpoints
+    of host-resident runs resume."""
+    data = {"x": np.arange(24, dtype=np.float32).reshape(12, 2),
+            "labels": np.arange(12, dtype=np.int64) % 3}
+    dev = Population.from_partition(data, 3, seed=0)
+    no_device_room(monkeypatch)
+    host = Population.from_partition(data, 3, seed=0)
+    assert dev.data["labels"].dtype == np.int32
+    assert host.data["labels"].dtype == np.int64
+    assert dev.fingerprint() == host.fingerprint()
+    host.load_state_dict(dev.state_dict())
+
+
+def test_gather_returns_fresh_buffers_safe_to_donate():
+    """Each gather is a new buffer, never the resident dataset, even for
+    the whole population in order; donating one leaves the data intact."""
+    data = synthetic_image_dataset(DATASETS["cifar10-syn"], 4 * N_LOCAL,
+                                   seed=0, image_hw=32)
+    pop = Population.from_client_list(
+        [{k: v[i * N_LOCAL:(i + 1) * N_LOCAL] for k, v in data.items()}
+         for i in range(4)])
+    whole = [0, 1, 2, 3]
+    a, b = pop.gather(whole), pop.gather(whole)
+    for k, v in data.items():
+        ptrs = {x[k].unsafe_buffer_pointer() for x in (a, b, pop.data)}
+        assert len(ptrs) == 3
+        np.testing.assert_array_equal(
+            np.asarray(a[k]).reshape(v.shape), v)
+    consume = jax.jit(lambda d: jax.tree.map(lambda v: v + 1, d),
+                      donate_argnums=0)
+    jax.block_until_ready(consume(a))
+    assert all(v.is_deleted() for v in a.values())
+    for k, v in data.items():
+        np.testing.assert_array_equal(np.asarray(pop.data[k]), v)
+        np.testing.assert_array_equal(np.asarray(b[k]).reshape(v.shape), v)
+
+
+def test_device_resident_round_matches_host_round(setup, monkeypatch):
+    """One engine round over the device-resident population gives the
+    same params, metrics and metered bytes, bit for bit, as the round fed
+    from the host gather, and the same partition fingerprint."""
+    cfg, split, data, _ = setup
+
+    def run():
+        pop = Population.from_partition(data, N_CLIENTS, scheme="dirichlet",
+                                        alpha=0.1, seed=0)
+        eng = FederatedEngine(
+            make_trainer(cfg, split, k=4), pop,
+            ClientSampler(pop.n_clients, 4, seed=3),
+            RoundScheduler(StragglerConfig(dropout_rate=0.25), seed=3))
+        eng.init(KEY)
+        _, metrics = eng.run_round()
+        return eng, metrics
+
+    dev, m_dev = run()
+    no_device_room(monkeypatch)
+    host, m_host = run()
+    assert (dev.population.where, host.population.where) == \
+        ("device", "host")
+    assert m_dev == m_host
+    for a, b in zip(jax.tree.leaves(dev.params),
+                    jax.tree.leaves(host.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert dev.trainer.meter.as_dict() == host.trainer.meter.as_dict()
+    assert dev.population.fingerprint() == host.population.fingerprint()
 
 
 # ------------------------------------------- cohort training + comm check
