@@ -1,7 +1,8 @@
 """Readings that the limits of `correct` are set from. Not run by the
 benchmark's own runs.
 
-    python3 bench/calibrate.py --workload <cell> seed [seed ...]
+    python3 bench/calibrate.py --workload <cell> [--seconds S] [--faults]
+        seed [seed ...]
 
 For each seed, in one process: the program's readings against the plain
 reference, as a run of the cell takes them, and the same readings of
@@ -9,10 +10,13 @@ reference, as a run of the cell takes them, and the same readings of
 - the control: the reference in the program's place, computed in the
   precision below the configuration's (bfloat16 for float32 at default
   precision);
-- each fault the cell can have, planted in the reference put in the
-  program's place: half of each batch left out with the mean taken over
-  the rest (a state returned unchanged reads 1 by construction and needs
-  no run).
+- each fault the cell can have. Federated rounds: planted in the
+  reference put in the program's place, half of each batch left out with
+  the mean taken over the rest (a state returned unchanged reads 1 by
+  construction and needs no run). A system module with a `calibration`
+  generator (split serving) reads its program through a window of
+  `--seconds` and, with `--faults`, plants each of its faults in the
+  program and reads it through the check requests.
 
 One JSON line per reading on standard output.
 """
@@ -60,6 +64,10 @@ def readings(r, fed):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
+    ap.add_argument("--seconds", type=float, default=15.0,
+                    help="window of a system with `calibration`")
+    ap.add_argument("--faults", action="store_true",
+                    help="also plant and read that system's faults")
     ap.add_argument("seeds", type=int, nargs="+")
     args = ap.parse_args(argv)
     spec = common.resolve(args.workload)
@@ -75,9 +83,11 @@ def main(argv=None) -> int:
         t0 = time.time()
         r = common.Run(config=spec["config"], traffic=spec["traffic"],
                        ref=common.load_module(spec["reference"]),
-                       limits=None, seed=seed, seconds=0,
+                       limits=None, seed=seed, seconds=args.seconds,
                        devices=devices, clock=clock, t_start=t0)
-        for who, got in readings(r, system):
+        gen = (system.calibration(r, faults=args.faults)
+               if hasattr(system, "calibration") else readings(r, system))
+        for who, got in gen:
             print(json.dumps({"cell": args.workload, "seed": seed,
                               "who": who, **got,
                               "wall_s": time.time() - t0,

@@ -4,9 +4,13 @@ and print what the compiler says they need.
     JAX_PLATFORMS=cpu python3 bench/rehearse.py [cell ...]
 
 For each cell: the federated round at the cell's cohort (clear or secure
-aggregation). Every argument is a shape on one described chip; nothing runs. Kernels are asked
-for by name (`impl="pallas"`), since code that asks which backend it is on
-sees the CPU here. Prints one JSON line per program with
+aggregation); for a serving cell, the engine's prefill at each prompt
+length of the mix, its paged decode at each bucket (one token, and each
+power of two up to `decode_block`, logits kept as the check requests
+keep them), and the reference's program that `correct` runs. Every
+argument is a shape on one described chip; nothing runs. Kernels are
+asked for by name (`impl="pallas"`), since code that asks which backend
+it is on sees the CPU here. Prints one JSON line per program with
 `memory_analysis()` in bytes.
 """
 from __future__ import annotations
@@ -14,6 +18,7 @@ from __future__ import annotations
 import json
 import os
 import sys
+from functools import partial
 from pathlib import Path
 
 sys.path[:0] = [str(Path(__file__).resolve().parents[1]),
@@ -77,6 +82,62 @@ def federated(spec, chip):
     yield f"round K={K} {'secure' if mix['secure'] else 'clear'}", compiled
 
 
+def serving(spec, chip):
+    from bench.systems import serving as sv
+    from repro.core import SplitConfig
+    from repro.core.split import SplitModel
+    from repro.runtime import WireSpec
+    from repro.serve.steps import (make_paged_decode_step,
+                                   make_paged_multi_decode_step,
+                                   make_tenant_prefill_step)
+
+    cfg, mix = spec["config"], spec["traffic"]
+    ref, srv, sp = common.load_module(spec["reference"]), cfg["serve"], \
+        cfg["split"]
+    model = SplitModel(sv.model_config(cfg), SplitConfig(
+        head_cycles=sp["head_layers"], tail_cycles=sp["tail_layers"],
+        prompt_len=sp["prompt_len"]), WireSpec.make(cfg["wire"]))
+    w = jax.eval_shape(lambda k: ref.make_weights(cfg, k),
+                       jax.random.PRNGKey(0))
+    prog = ref.to_program(w, cfg)
+    one = lambda t: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), t)
+    tail, prompt = one(prog["tails"]), one(prog["prompts"])
+    S, page = srv["n_slots"], srv["page_size"]
+    nb = -(-srv["max_seq"] // page)
+    pool = jax.eval_shape(lambda: model.init_paged_cache(S * nb + 2, page))
+    blank = jax.eval_shape(lambda: model.blank_slot_cache(nb * page))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    prefill = jax.jit(make_tenant_prefill_step(model, impl="pallas"))
+    for p in sorted(mix["prompt_lengths"]):
+        yield f"prefill {p} tokens", prefill.lower(
+            on(chip, prog["shared"]), on(chip, tail),
+            on(chip, prompt), on(chip, {"tokens": i32(1, p)}),
+            on(chip, blank)).compile()
+    n = 1
+    while n <= srv["decode_block"]:
+        step = (make_paged_decode_step(model, impl="pallas") if n == 1 else
+                make_paged_multi_decode_step(model, n, impl="pallas"))
+        last = (jax.ShapeDtypeStruct((S,), jnp.float32) if n == 1
+                else i32(S))
+        yield f"decode {n} x {S} slots", jax.jit(
+            step, donate_argnums=(6,)).lower(
+            on(chip, prog["shared"]), on(chip, prog["tails"]),
+            on(chip, i32(S)), on(chip, i32(S)), on(chip, i32(S)),
+            on(chip, last),
+            on(chip, pool), on(chip, i32(S, nb))).compile()
+        n *= 2
+    N = max(mix["output_lengths"])
+    gaps = jax.jit(partial(sv._reference_gaps, ref=ref, cfg=cfg,
+                           prec=sv.precision(cfg["matmul_precision"]),
+                           with_logits=True, control=False))
+    yield "reference", gaps.lower(
+        on(chip, w), on(chip, i32(srv["max_seq"] - sp["prompt_len"])),
+        on(chip, i32()), on(chip, i32()), on(chip, i32(N)),
+        on(chip, jax.ShapeDtypeStruct((N, cfg["vocab_size"]),
+                                      jnp.float32))).compile()
+
+
 def main(argv) -> int:
     from jax.experimental import topologies
     topo = topologies.get_topology_desc(platform="tpu",
@@ -86,7 +147,9 @@ def main(argv) -> int:
     cells = argv or [w["name"] for w in bench["workloads"]]
     for cell in cells:
         spec = common.resolve(cell, bench)
-        for what, compiled in federated(spec, chip):
+        programs = (serving if spec["config"]["system"] == "serving"
+                    else federated)
+        for what, compiled in programs(spec, chip):
             print(json.dumps({"cell": cell, "program": what,
                               **memory(compiled)}), flush=True)
     return 0

@@ -13,10 +13,11 @@ checked on a synthetic trace (tests/bench):
 
 - busy: the union of the operation intervals of a chip, clipped to the
   window, averaged over the chips;
-- time by op name: the summed durations of the operations that share a
-  name once the `.<n>` suffix is dropped (a Pallas kernel's custom call is
-  named after the kernel: `%<kernel>.<n> = ... custom-call(...)`; other
-  ops that merely take its result as an operand do not count);
+- time and count by op name: the summed durations, and the number, of
+  the operations that share a name once the `.<n>` suffix is dropped (a
+  Pallas kernel's custom call is named after the kernel: `%<kernel>.<n>
+  = ... custom-call(...)`; other ops that merely take its result as an
+  operand do not count);
 - device ops: the operations that took most time in all (a loop or a
   branch counts through the ops of its body, not as one op);
 - idle gaps: each gap between busy intervals, charged to the innermost
@@ -112,14 +113,14 @@ def base_name(name: str) -> str:
 
 def reduce(devices: Dict[str, List[Tuple[float, float, str]]],
            host: List[Tuple[float, float, str]], *, top: int = 10) -> Dict:
-    """busy_s and window_s (averaged over chips), device seconds by op
-    name, and the breakdown of device ops and idle gaps."""
+    """busy_s and window_s (averaged over chips), device seconds and
+    calls by op name, and the breakdown of device ops and idle gaps."""
     windows = [(s, e) for s, e, n in host if n == WINDOW_SPAN]
     if not windows or not devices:
         raise ValueError("the trace holds no bench.window span or no chip")
     lo, hi = windows[0][0], windows[0][1]
     spans = [(s, e, n) for s, e, n in host if n != WINDOW_SPAN]
-    busy, by_name = [], defaultdict(float)
+    busy, by_name, count = [], defaultdict(float), defaultdict(int)
     op_time: Dict[str, float] = defaultdict(float)
     gap_time: Dict[str, float] = defaultdict(float)
     for ops in devices.values():
@@ -130,6 +131,7 @@ def reduce(devices: Dict[str, List[Tuple[float, float, str]]],
             if lo <= s < hi and not name.startswith(CONTAINERS):
                 op_time[name] += (min(e, hi) - s) / 1e9
                 by_name[base_name(name)] += (min(e, hi) - s) / 1e9
+                count[base_name(name)] += 1
         edges = [lo] + [x for iv in merged for x in iv] + [hi]
         for g0, g1 in zip(edges[0::2], edges[1::2]):
             if g1 > g0:
@@ -141,6 +143,7 @@ def reduce(devices: Dict[str, List[Tuple[float, float, str]]],
         "busy_s": sum(busy) / n,
         "window_s": (hi - lo) / 1e9,
         "op_s": {k: v / n for k, v in by_name.items()},
+        "op_n": {k: v / n for k, v in count.items()},
         "breakdown": {"device_ops": rank(op_time),
                       "idle_gaps": rank(gap_time)},
     }

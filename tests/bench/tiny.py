@@ -1,5 +1,7 @@
 """Tiny stand-ins for the benchmark's configurations and mixes, so the
-harness, its reference and its faults run on the CPU in a test."""
+harness, its reference and its faults run on the CPU in a test: vit-base
+for the federated rounds, qwen2.5-14b's family (GQA, QKV bias, SwiGLU,
+RMSNorm, untied output layer) for split serving."""
 import copy
 import time
 
@@ -46,3 +48,17 @@ def run(config, traffic, ref_name, cell, *, seed=7, seconds=0.6,
 
 def correct(out) -> bool:
     return all(c["value"] <= c["limit"] for c in out["checks"])
+
+QWEN = copy.deepcopy(common.load_json(common.BENCH / "configs"
+                                      / "qwen2.5-14b.json"))
+# rope_theta 1e4: at 8 frequencies and 32 positions, 1e6 would turn a
+# position off by one into a rotation too small to see
+QWEN.update(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+            head_dim=16, intermediate_size=128, vocab_size=256,
+            rope_theta=10000.0)
+QWEN["serve"] = dict(QWEN["serve"], max_seq=32, page_size=8, decode_block=4)
+
+CLOSED = dict(common.load_json(common.BENCH / "traffic"
+                               / "q14b-decode-closed.json"),
+              prompt_lengths=[4, 8], output_lengths=[3, 6], margin_sample=3,
+              drain_seconds=30)
