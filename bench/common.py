@@ -5,12 +5,17 @@ and the checks' part of the result line.
 A cell is one entry of `workloads` in `BENCHMARK.json`. Everything about
 it is found by name, each in a file of its own:
 
-    bench/configs/<config>.json    sizes, deployment, precision, `system`
+    bench/configs/<config>.json    sizes, deployment, precision, `system`,
+                                   `program` (its `repro.configs` arch)
     bench/configs/<config>.py      the plain reference, weights from a seed
     bench/traffic/<traffic>.json   parameters the general generator reads
     bench/systems/<system>.py      what runs that kind of system
     bench/metrics/<metric>.py      one reader per per-layer metric
     bench/limits/<cell>.json       the limits of `correct`
+
+The program's model is the architecture the configuration names, at the
+configuration's sizes (`program_model`): a new configuration of an
+architecture the program has is a data file, not code.
 
 A metric's reader gets the reduced trace of the window, the chip's peaks,
 the cell's configuration and traffic mix, and the system's raw record of
@@ -20,6 +25,7 @@ from those shapes.
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib.util
 import json
 import os
@@ -88,6 +94,103 @@ def resolve(cell_name: str, bench: Optional[Dict[str, Any]] = None
 
 def metric_reader(name: str):
     return load_module(BENCH / "metrics" / f"{name}.py").read
+
+
+# ------------------------------------------------------------------ program
+# A configuration's published keys and the `ModelConfig` field each sets,
+# as a dotted path into the nested configs.
+PROGRAM_KEYS = {
+    "hidden_size": "d_model",
+    "num_hidden_layers": "n_layers",
+    "intermediate_size": "d_ff",
+    "vocab_size": "vocab_size",
+    "num_labels": "num_classes",
+    "tie_word_embeddings": "tie_embeddings",
+    "first_k_dense_replace": "n_dense_layers",
+    "num_attention_heads": "attention.n_heads",
+    "num_key_value_heads": "attention.n_kv_heads",
+    "head_dim": "attention.head_dim",
+    "rope_theta": "attention.rope_theta",
+    "qkv_bias": "attention.qkv_bias",
+    "q_lora_rank": "attention.mla.q_lora_rank",
+    "kv_lora_rank": "attention.mla.kv_lora_rank",
+    "qk_nope_head_dim": "attention.mla.qk_nope_head_dim",
+    "qk_rope_head_dim": "attention.mla.qk_rope_head_dim",
+    "v_head_dim": "attention.mla.v_head_dim",
+    "n_routed_experts": "moe.n_experts",
+    "num_experts_per_tok": "moe.top_k",
+    "moe_intermediate_size": "moe.d_ff_expert",
+    "n_shared_experts": "moe.n_shared_experts",
+}
+SPLIT_LAYERS = ("head_layers", "body_layers", "tail_layers")
+
+
+def _replace(obj, changes: Dict[str, Any], where: str):
+    """`obj` with `changes` ({field: value or {field: ...}}) made, each
+    nested config rebuilt once, so that a config checks itself only with
+    all of its new values."""
+    new = {}
+    for name, value in changes.items():
+        if isinstance(value, dict):
+            inner = getattr(obj, name)
+            if inner is None:
+                raise ValueError(f"{where} has no {name} config to set "
+                                 f"{sorted(value)} in")
+            value = _replace(inner, value, f"{where}.{name}")
+        new[name] = value
+    return dataclasses.replace(obj, **new)
+
+
+def program_model(config: Dict[str, Any], **split):
+    """The program's `ModelConfig` and `SplitConfig` for a configuration.
+
+    The architecture is the program's own, `repro.configs.get_config` of
+    `config["program"]["arch"]`; every key of `PROGRAM_KEYS` the
+    configuration gives then sets its field, and `program["fields"]`
+    ({field path: value}) sets the program-only fields after those. Where
+    the depth differs from the architecture's, the name says so, as
+    `launch/serve.py` names it. The split's `head_layers`, `body_layers`
+    and `tail_layers` are layers: the dense prefix is the head's, the rest
+    are whole cycles of the layer pattern, and `SplitModel` has to give
+    all three counts. Its other keys, and `split`, are `SplitConfig`
+    fields."""
+    from repro.configs import get_config
+    from repro.core.split import SplitConfig, SplitModel
+
+    base = get_config(config["program"]["arch"])
+    changes: Dict[str, Any] = {}
+    paths = [(PROGRAM_KEYS[k], v) for k, v in config.items()
+             if k in PROGRAM_KEYS]
+    paths += list(config["program"].get("fields", {}).items())
+    for path, value in paths:
+        *outer, last = path.split(".")
+        node = changes
+        for name in outer:
+            node = node.setdefault(name, {})
+        node[last] = value
+    if changes.get("n_layers", base.n_layers) != base.n_layers:
+        changes.setdefault("name", f"{base.name}-{changes['n_layers']}L")
+    cfg = _replace(base, changes, base.name)
+
+    sp = config["split"]
+    cyc = len(cfg.layer_pattern)
+    want = [sp[k] for k in SPLIT_LAYERS]
+    patterned = [want[0] - cfg.n_dense_layers, want[2]]
+    if min(patterned) < 0 or any(n % cyc for n in patterned + want[1:2]):
+        raise ValueError(
+            f"{cfg.name}: split {dict(zip(SPLIT_LAYERS, want))} is not "
+            f"whole cycles of {cfg.layer_pattern} after "
+            f"{cfg.n_dense_layers} dense prefix layers")
+    split = SplitConfig(head_cycles=patterned[0] // cyc,
+                        tail_cycles=patterned[1] // cyc,
+                        **{k: v for k, v in sp.items()
+                           if k not in SPLIT_LAYERS}, **split)
+    model = SplitModel(cfg, split)
+    got = [model.n_head_layers, model.n_body_layers, model.n_tail_layers]
+    if got != want:
+        raise ValueError(f"{cfg.name}: the split gives {got} head, body "
+                         f"and tail layers, the configuration {want}")
+    return cfg, split
 
 
 # ------------------------------------------------------------- environment

@@ -46,18 +46,16 @@ def on(chip, tree):
 
 
 def federated(spec, chip):
-    from bench.systems import federated as fed
-    from repro.core import ProtocolConfig, SFPromptTrainer, SplitConfig
+    from repro.core import ProtocolConfig, SFPromptTrainer
     from repro.core.aggregation import get_aggregator
     from repro.core.split import SplitModel
     from repro.runtime import WireSpec
 
     cfg, mix = spec["config"], spec["traffic"]
     ref = common.load_module(spec["reference"])
-    sp, prog = cfg["split"], cfg["program"]
-    model = SplitModel(fed.model_config(cfg), SplitConfig(
-        head_cycles=sp["head_layers"], tail_cycles=sp["tail_layers"],
-        prompt_len=sp["prompt_len"], prune_gamma=sp["prune_gamma"]),
+    prog = cfg["program"]
+    model = SplitModel(
+        *common.program_model(cfg, local_epochs=mix["local_epochs"]),
         WireSpec.make(cfg["wire"], impl="pallas"))
     trainer = SFPromptTrainer(model, ProtocolConfig(
         clients_per_round=mix["cohort"], local_epochs=mix["local_epochs"],
@@ -84,7 +82,6 @@ def federated(spec, chip):
 
 def serving(spec, chip):
     from bench.systems import serving as sv
-    from repro.core import SplitConfig
     from repro.core.split import SplitModel
     from repro.runtime import WireSpec
     from repro.serve.steps import (make_paged_decode_step,
@@ -94,9 +91,7 @@ def serving(spec, chip):
     cfg, mix = spec["config"], spec["traffic"]
     ref, srv, sp = common.load_module(spec["reference"]), cfg["serve"], \
         cfg["split"]
-    model = SplitModel(sv.model_config(cfg), SplitConfig(
-        head_cycles=sp["head_layers"], tail_cycles=sp["tail_layers"],
-        prompt_len=sp["prompt_len"]), WireSpec.make(cfg["wire"]))
+    model = SplitModel(*common.program_model(cfg), WireSpec.make(cfg["wire"]))
     w = jax.eval_shape(lambda k: ref.make_weights(cfg, k),
                        jax.random.PRNGKey(0))
     prog = ref.to_program(w, cfg)

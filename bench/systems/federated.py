@@ -36,21 +36,6 @@ F32 = jnp.float32
 
 
 # ------------------------------------------------------------------ program
-def model_config(config: Dict[str, Any]):
-    from repro.models.config import AttentionConfig, ModelConfig
-    return ModelConfig(
-        name=config["name"], arch_type="vit",
-        n_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
-        d_ff=config["intermediate_size"], vocab_size=1,
-        layer_pattern=("attn",),
-        attention=AttentionConfig(
-            n_heads=config["num_attention_heads"],
-            n_kv_heads=config["num_attention_heads"],
-            head_dim=config["head_dim"], use_rope=False),
-        mlp_activation="gelu", norm="layernorm",
-        num_classes=config["num_labels"], max_seq_len=512)
-
-
 def keep_count(n_local: int, gamma: float, batch: int) -> int:
     """Samples a client keeps for phase 2: n - floor(gamma n), at least a
     batch, rounded down to whole batches."""
@@ -60,21 +45,15 @@ def keep_count(n_local: int, gamma: float, batch: int) -> int:
 
 def build(r: common.Run, weights, data, rows, sizes):
     """The engine under test, built through the program's public API."""
-    from repro.core import ProtocolConfig, SFPromptTrainer, SplitConfig
+    from repro.core import ProtocolConfig, SFPromptTrainer
     from repro.core.aggregation import get_aggregator
     from repro.core.split import SplitModel
     from repro.fed import ClientSampler, FederatedEngine, Population
     from repro.runtime import WireSpec
 
     cfg, mix, prog = r.config, r.traffic, r.config["program"]
-    sp = cfg["split"]
     model = SplitModel(
-        model_config(cfg),
-        SplitConfig(head_cycles=sp["head_layers"],
-                    tail_cycles=sp["tail_layers"],
-                    prompt_len=sp["prompt_len"],
-                    prune_gamma=sp["prune_gamma"],
-                    local_epochs=mix["local_epochs"]),
+        *common.program_model(cfg, local_epochs=mix["local_epochs"]),
         WireSpec.make(cfg["wire"]))
     pcfg = ProtocolConfig(
         clients_per_round=mix["cohort"], local_epochs=mix["local_epochs"],

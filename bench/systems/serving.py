@@ -60,41 +60,15 @@ from bench import common, traffic
 
 
 # ------------------------------------------------------------------ program
-def model_config(config: Dict[str, Any]):
-    """The program's `ModelConfig` at the configuration's sizes."""
-    from repro.models.config import AttentionConfig, ModelConfig
-    return ModelConfig(
-        name=f"{config['name']}-{config['num_hidden_layers']}L",
-        arch_type="dense", n_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"], d_ff=config["intermediate_size"],
-        vocab_size=config["vocab_size"], layer_pattern=("attn",),
-        attention=AttentionConfig(
-            n_heads=config["num_attention_heads"],
-            n_kv_heads=config["num_key_value_heads"],
-            head_dim=config["head_dim"], rope_theta=config["rope_theta"],
-            qkv_bias=config["qkv_bias"]),
-        mlp_activation="silu_glu", norm="rmsnorm",
-        tie_embeddings=config["tie_word_embeddings"],
-        max_seq_len=config["serve"]["max_seq"])
-
-
 def build(r: common.Run, prog: Dict[str, Any], tracer):
     """The engine under test, built through the program's public API over
     the weight buffers `prog` (the reference's `to_program` layout)."""
-    from repro.core.split import SplitConfig, SplitModel
+    from repro.core.split import SplitModel
     from repro.runtime import WireSpec
     from repro.serve import PagedServeConfig, PagedServeEngine, TenantBank
 
     cfg, sv = r.config, r.config["serve"]
-    sp = cfg["split"]
-    model = SplitModel(model_config(cfg),
-                       SplitConfig(head_cycles=sp["head_layers"],
-                                   tail_cycles=sp["tail_layers"],
-                                   prompt_len=sp["prompt_len"]),
-                       WireSpec.make(cfg["wire"]))
-    if model.n_body_layers != sp["body_layers"]:
-        raise ValueError(f"the split gives {model.n_body_layers} body "
-                         f"layers, the configuration {sp['body_layers']}")
+    model = SplitModel(*common.program_model(cfg), WireSpec.make(cfg["wire"]))
     return PagedServeEngine(
         model, prog["shared"], TenantBank(prog["tails"], prog["prompts"]),
         PagedServeConfig(n_slots=sv["n_slots"], max_seq=sv["max_seq"],

@@ -57,16 +57,13 @@ def test_weights_are_the_program_layout_without_a_copy():
     """`to_program` regroups the reference's arrays into `SplitModel`'s
     head and body and the tenant bank's stacked tails: every leaf is the
     same object, with the shape the program would make itself."""
-    from repro.core.split import SplitConfig, SplitModel
+    from repro.core.split import SplitModel
     cfg = tiny.QWEN
     w = jax.jit(lambda k: REF.make_weights(cfg, k))(jax.random.PRNGKey(0))
     prog = REF.to_program(w, cfg)
     ids = {id(x) for x in jax.tree.leaves(w)}
     assert {id(x) for x in jax.tree.leaves(prog)} == ids
-    sp = cfg["split"]
-    model = SplitModel(serving.model_config(cfg), SplitConfig(
-        head_cycles=sp["head_layers"], tail_cycles=sp["tail_layers"],
-        prompt_len=sp["prompt_len"]))
+    model = SplitModel(*common.program_model(cfg))
     init = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     shape = lambda t: jax.tree.map(lambda x: (x.shape, x.dtype), t)
     assert shape(prog["shared"]) == shape({"head": init["head"],
@@ -77,12 +74,18 @@ def test_weights_are_the_program_layout_without_a_copy():
     assert prog["prompts"].shape == (1,) + init["prompt"].shape
 
 
-def test_model_config_is_the_launchers_at_published_sizes():
-    """The engine is built at the shapes `launch/serve.py` serves with
-    `--no-reduced --layers 3`."""
+@pytest.mark.parametrize("cell,layers", [("q14b-decode-closed", 3),
+                                         ("vitb-fed-u10", None)])
+def test_model_config_is_the_launchers_at_published_sizes(cell, layers):
+    """Each cell's model is built at the shapes its launcher runs:
+    `launch/serve.py --no-reduced --layers 3` serves qwen2.5-14b, and
+    `launch/train.py` without `--reduced` trains the published vit-base,
+    the registry's config that `served_config` returns with no cut."""
     from repro.launch.serve import served_config
-    mine = serving.model_config(PUBLISHED)
-    theirs = served_config("qwen2.5-14b", reduced=False, layers=3)
+    config = common.resolve(cell)["config"]
+    mine, _ = common.program_model(config)
+    theirs = served_config(config["program"]["arch"], reduced=False,
+                           layers=layers)
     for field in ("arch_type", "n_layers", "d_model", "d_ff", "vocab_size",
                   "layer_pattern", "attention", "mlp_activation", "norm",
                   "tie_embeddings", "final_logit_softcap", "n_dense_layers",

@@ -10,8 +10,8 @@ from bench import common
 VIT = copy.deepcopy(common.load_json(common.BENCH / "configs"
                                      / "vit-base.json"))
 VIT.update(hidden_size=32, num_hidden_layers=3, num_attention_heads=2,
-           head_dim=16, intermediate_size=64, image_size=8, patch_size=4,
-           num_labels=10)
+           num_key_value_heads=2, head_dim=16, intermediate_size=64,
+           image_size=8, patch_size=4, num_labels=10)
 VIT["split"] = dict(VIT["split"], body_layers=1, prompt_len=2)
 
 ROUNDS = {"clients": 4, "samples_per_client": 8, "client_sizes": [8],
