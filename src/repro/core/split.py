@@ -38,6 +38,10 @@ from repro.runtime.boundary import WireSpec
 
 Params = Dict[str, Any]
 
+# what a training body layer keeps for its backward pass (`body_fwd`)
+BODY_TRAIN_SAVES = jax.checkpoint_policies.save_only_these_names(
+    L.QKV, L.MLP_PRE)
+
 
 @dataclass(frozen=True)
 class SplitConfig:
@@ -404,12 +408,13 @@ class SplitModel:
         return x, base, base, n_prefix
 
     # -------------------------------------------------------------- segments
-    def _seg_fwd(self, seg_p, seg_name, n_cycles, x, ctx, cache):
+    def _seg_fwd(self, seg_p, seg_name, n_cycles, x, ctx, cache,
+                 policy=None):
         cfg = self.cfg
         caches = cache["stack"] if cache is not None else None
         x, aux, new_stack = run_stack(
             cfg, seg_p["stack"], cfg.layer_pattern, x, ctx, caches,
-            shared=seg_p.get("shared_attn"))
+            shared=seg_p.get("shared_attn"), policy=policy)
         new_cache = None
         if cache is not None:
             new_cache = dict(cache)
@@ -482,10 +487,18 @@ class SplitModel:
                      has_context=head_out.get("has_context", False))
 
     def body_fwd(self, body_p, smashed, head_out, *, cache=None):
-        """Server-side: frozen body over the smashed activations."""
+        """Server-side: frozen body over the smashed activations.
+
+        In training the body is differentiated for its input only, so its
+        layers keep just q/k/v and the MLP's pre-activation for the
+        backward pass and recompute the rest there from them: norms,
+        activations, the S x S scores and softmax, which would otherwise
+        be stacked per layer, and the attention's output."""
         ctx = self._ctx_from(head_out)
+        policy = (BODY_TRAIN_SAVES if ctx.mode == "train" and cache is None
+                  else None)
         x, aux, new_cache = self._seg_fwd(
-            body_p, "body", self.body_cycles, smashed, ctx, cache)
+            body_p, "body", self.body_cycles, smashed, ctx, cache, policy)
         return {"smashed": x, "aux": aux, "cache": new_cache}
 
     def tail_fwd(self, tail_p, x, head_out, batch=None, *, cache=None,

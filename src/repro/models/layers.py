@@ -12,6 +12,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.kernels.flash_attention.decode import (decode_attention,
                                                   paged_decode_attention)
@@ -21,6 +22,11 @@ from repro.kernels.rwkv6_scan.ops import rwkv6_scan
 from repro.models.config import AttentionConfig, ModelConfig
 
 Params = Dict[str, Any]
+
+# names of the matmul outputs a checkpoint policy may keep for the backward
+# pass (`jax.checkpoint_policies.save_only_these_names`); untagged values
+# are recomputed from them
+QKV, MLP_PRE = "qkv", "mlp_pre"
 
 
 # ---------------------------------------------------------------- helpers
@@ -231,9 +237,12 @@ def apply_attention(p: Params, cfg: ModelConfig, x: jnp.ndarray, ctx: Ctx,
     if att.mla is not None:
         return _apply_mla(p, cfg, x, h, ctx, cache, window)
 
-    q = dense(p["q"], h).reshape(B, S, att.n_heads, att.head_dim)
-    k = dense(p["k"], h).reshape(B, S, att.n_kv_heads, att.head_dim)
-    v = dense(p["v"], h).reshape(B, S, att.n_kv_heads, att.head_dim)
+    q = checkpoint_name(dense(p["q"], h), QKV).reshape(
+        B, S, att.n_heads, att.head_dim)
+    k = checkpoint_name(dense(p["k"], h), QKV).reshape(
+        B, S, att.n_kv_heads, att.head_dim)
+    v = checkpoint_name(dense(p["v"], h), QKV).reshape(
+        B, S, att.n_kv_heads, att.head_dim)
 
     if att.use_rope:
         if att.mrope_sections is not None and ctx.positions.ndim == 3:
@@ -410,9 +419,12 @@ def _act(x, kind: str):
 def apply_mlp(p: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     h = apply_norm(p["ln"], x, cfg.norm)
     if cfg.mlp_activation.endswith("_glu"):
-        h = _act(dense(p["gate"], h), cfg.mlp_activation) * dense(p["up"], h)
+        h = (_act(checkpoint_name(dense(p["gate"], h), MLP_PRE),
+                  cfg.mlp_activation)
+             * checkpoint_name(dense(p["up"], h), MLP_PRE))
     else:
-        h = _act(dense(p["up"], h), cfg.mlp_activation)
+        h = _act(checkpoint_name(dense(p["up"], h), MLP_PRE),
+                 cfg.mlp_activation)
     return dense(p["down"], h)
 
 

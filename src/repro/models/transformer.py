@@ -99,10 +99,12 @@ def init_stack(key, cfg: ModelConfig, kind: str, n: int) -> Params:
 
 
 def run_stack(cfg: ModelConfig, stacked: Params, kinds, x, ctx: L.Ctx,
-              caches=None, shared: Optional[Params] = None):
+              caches=None, shared: Optional[Params] = None, policy=None):
     """Scan a stacked layer group. `stacked` maps 'pos{i}' -> stacked params
     for cycle position i; `caches` mirrors that layout (or None).
-    Returns (x, aux_loss, new_caches)."""
+    `ctx.remat` recomputes each step whole in the backward pass; else a
+    `jax.checkpoint` `policy` picks what each step keeps for it and
+    recomputes the rest. Returns (x, aux_loss, new_caches)."""
     has_cache = caches is not None
 
     def body(carry, xs):
@@ -120,6 +122,8 @@ def run_stack(cfg: ModelConfig, stacked: Params, kinds, x, ctx: L.Ctx,
 
     if ctx.remat:
         body = jax.checkpoint(body)
+    elif policy is not None:
+        body = jax.checkpoint(body, policy=policy, prevent_cse=False)
     xs = (stacked, caches) if has_cache else stacked
     (x, aux), new_caches = jax.lax.scan(
         body, (x, jnp.float32(0.0)), xs, unroll=True if ctx.unroll else 1)
