@@ -1,7 +1,8 @@
 """DeepSeek-V3 671B [arXiv:2412.19437] — 61L, d_model=7168, MLA with 128
 heads (q_lora 1536, kv_lora 512, nope/rope head dims 128/64, v 128); first 3
 layers dense (d_ff=18432), remaining 58 MoE with 1 shared + 256 routed
-experts top-8 (expert d_ff=2048); multi-token-prediction head; vocab 129280.
+experts top-8 (expert d_ff=2048); vocab 129280. The multi-token-prediction
+head is a pre-training objective and is not part of the split model.
 MLA's compressed decode cache (576 floats/token/layer) is what makes the
 decode_32k/long_500k shapes cheap."""
 from repro.models.config import (AttentionConfig, MLAConfig, ModelConfig,
@@ -23,7 +24,6 @@ CONFIG = ModelConfig(
                       v_head_dim=128)),
     moe=MoEConfig(n_experts=256, top_k=8, d_ff_expert=2048,
                   n_shared_experts=1),
-    mtp=True,
     mlp_activation="silu_glu",
     norm="rmsnorm",
     max_seq_len=131_072,

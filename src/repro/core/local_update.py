@@ -25,7 +25,7 @@ def local_loss_fn(model: SplitModel, head_p, trainable, batch, *,
     """L_C(x; (W_h, W_t); p): loss of the head->tail local model."""
     tail_p, prompt = trainable["tail"], trainable["prompt"]
     ho = model.head_fwd(head_p, prompt, batch, mode="train", impl=impl)
-    to = model.tail_fwd(tail_p, ho["smashed"], ho, batch)
+    to = model.tail_fwd(tail_p, ho["smashed"], ho)
     out = {"logits": to["logits"], "n_prefix": to.get("n_prefix", 0),
            "aux": ho["aux"] + to["aux"]}
     return losses.task_loss(model.cfg, out, batch, impl=impl)

@@ -56,25 +56,15 @@ def classifier_el2n(logits: jnp.ndarray, labels: jnp.ndarray,
     return el2n, ce
 
 
-def task_loss(cfg, out: Dict, batch: Dict, *, impl: str = "auto",
-              mtp_weight: float = 0.3):
-    """Dispatch on arch type; adds MoE aux loss and the DeepSeek MTP term."""
+def task_loss(cfg, out: Dict, batch: Dict, *, impl: str = "auto"):
+    """Dispatch on arch type; adds the MoE aux loss."""
     if cfg.num_classes:
         loss, metrics = classifier_loss(out["logits"], batch["labels"],
                                         impl=impl)
     else:
         loss, metrics = lm_loss(out["logits"], batch["tokens"],
                                 out.get("n_prefix", 0), impl=impl)
-        if "mtp_logits" in out:
-            # MTP predicts token t+2 from position t
-            mlg = out["mtp_logits"][:, :-1, :]
-            mtg = batch["tokens"][:, 2:]
-            V = mlg.shape[-1]
-            _, mce = el2n_scores(mlg.reshape(-1, V), mtg.reshape(-1),
-                                 impl="ref")
-            metrics["mtp_ce"] = mce.mean()
-            loss = loss + mtp_weight * mce.mean()
-    loss = loss + out.get("aux", out.get("aux_loss", 0.0))
+    loss = loss + out.get("aux", 0.0)
     metrics["loss"] = loss
     return loss, metrics
 

@@ -215,7 +215,7 @@ class SFPromptTrainer:
         bo = model.body_fwd(params_frozen["body"], x_hb, ho)
         x_bt, b_bt = model.wire.body_tail.transmit(
             bo["smashed"], key=k_bt, train=True)
-        to = model.tail_fwd(trainable["tail"], x_bt, ho, batch)
+        to = model.tail_fwd(trainable["tail"], x_bt, ho)
         out = {"logits": to["logits"], "n_prefix": to.get("n_prefix", 0),
                "aux": ho["aux"] + bo["aux"] + to["aux"]}
         loss, _ = losses.task_loss(model.cfg, out, batch, impl=pcfg.impl)

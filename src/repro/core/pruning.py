@@ -57,7 +57,7 @@ def score_client_data(model: SplitModel, head_p, tail_p, prompt,
 
     def score_batch(_, batch):
         ho = model.head_fwd(head_p, prompt, batch, mode="train", impl=impl)
-        to = model.tail_fwd(tail_p, ho["smashed"], ho, batch)
+        to = model.tail_fwd(tail_p, ho["smashed"], ho)
         out = {"logits": to["logits"], "n_prefix": to.get("n_prefix", 0)}
         return None, losses.task_el2n(model.cfg, out, batch, impl=impl)
 

@@ -122,12 +122,6 @@ class SplitModel:
         tail["final_norm"] = L.norm_init(cfg.d_model, cfg.norm)
         out_dim = cfg.num_classes or cfg.vocab_size
         tail["head"] = L.dense_init(next(keys), cfg.d_model, out_dim)
-        if cfg.mtp:
-            tail["mtp"] = {
-                "proj": L.dense_init(next(keys), 2 * cfg.d_model, cfg.d_model),
-                "block": init_block(next(keys), cfg, "attn"),
-                "norm": L.norm_init(cfg.d_model, cfg.norm),
-            }
 
         if self._has_shared:
             sh = init_block(next(keys), cfg, "shared_attn")
@@ -360,13 +354,6 @@ class SplitModel:
         B, S = toks.shape
         x = jnp.take(emb["tok"].astype(dtype), toks, axis=0)
         n_prefix = 0
-        if cfg.arch_type == "audio":
-            if mode == "decode":
-                apos = batch["pos"][:, None]
-            else:
-                apos = jnp.broadcast_to(
-                    jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-            x = x + L.sinusoidal_embedding(apos, cfg.d_model).astype(dtype)
         if cfg.arch_type == "vlm" and "patch_embeds" in batch:
             pe = batch["patch_embeds"].astype(dtype)
             x = jnp.concatenate([pe, x], axis=1)
@@ -388,6 +375,11 @@ class SplitModel:
         else:
             base = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
         base = base.astype(jnp.int32)
+        if cfg.arch_type == "audio":
+            # whisper decoder: absolute sinusoidal positions on the tokens,
+            # at their sequence index past the prefix, as decode's `pos`
+            x = x.at[:, T - S:].add(L.sinusoidal_embedding(
+                base[:, T - S:], cfg.d_model).astype(dtype))
         att = cfg.attention
         if att is not None and att.mrope_sections is not None:
             # M-RoPE: layout is [prompt | patches | text]; patch grid
@@ -501,7 +493,7 @@ class SplitModel:
             body_p, "body", self.body_cycles, smashed, ctx, cache, policy)
         return {"smashed": x, "aux": aux, "cache": new_cache}
 
-    def tail_fwd(self, tail_p, x, head_out, batch=None, *, cache=None,
+    def tail_fwd(self, tail_p, x, head_out, *, cache=None,
                  last_only: bool = False):
         """Client-side: tail layers -> final norm -> task head.
         last_only=True computes logits for the final position only — the
@@ -524,11 +516,6 @@ class SplitModel:
             logits = c * jnp.tanh(logits / c)
         out["logits"] = logits
         out["n_prefix"] = head_out["n_prefix"]
-        if cfg.mtp and head_out["mode"] == "train" and batch is not None:
-            toks = batch["tokens"]
-            # embedding lives in the head segment; MTP needs it — the client
-            # holds both, so this is local (no extra communication).
-            out["mtp_hidden_ready"] = True
         return out
 
     # -------------------------------------------------------------- routes
@@ -570,7 +557,7 @@ class SplitModel:
                 new_cache["body"] = bo["cache"]
             x, wire_bytes["body_tail"] = self.wire.body_tail.transmit(
                 x, key=k_bt, train=train)
-        to = self.tail_fwd(params["tail"], x, ho, batch,
+        to = self.tail_fwd(params["tail"], x, ho,
                            cache=cache["tail"] if cache else None,
                            last_only=(mode == "prefill" and last_only))
         out = dict(to)

@@ -65,7 +65,7 @@ def make_split_loss(model: SplitModel, *, impl="ref", remat=True,
                                   batch["tokens"], ho["n_prefix"],
                                   cfg.final_logit_softcap)
             return loss + ho["aux"] + bo["aux"] + aux_t
-        to = model.tail_fwd(trainable["tail"], x_bt, ho, batch)
+        to = model.tail_fwd(trainable["tail"], x_bt, ho)
         out = {"logits": to["logits"].astype(jnp.float32),
                "n_prefix": to.get("n_prefix", 0),
                "aux": ho["aux"] + bo["aux"] + to["aux"]}

@@ -1,4 +1,3 @@
 from repro.models.config import (  # noqa: F401
     AttentionConfig, EncoderConfig, Mamba2Config, MLAConfig, ModelConfig,
     MoEConfig, RWKV6Config)
-from repro.models.transformer import Transformer  # noqa: F401

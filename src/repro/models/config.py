@@ -103,7 +103,6 @@ class ModelConfig:
     num_classes: Optional[int] = None   # ViT-style classifier head
     encoder: Optional[EncoderConfig] = None
     n_dense_layers: int = 0             # leading dense layers in MoE stacks
-    mtp: bool = False                   # DeepSeek multi-token-prediction head
     max_seq_len: int = 8192
     # Ring-buffer sliding-window decode cache used for long_500k on attention
     # archs without native sub-quadratic structure (beyond-paper feature).

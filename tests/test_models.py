@@ -1,7 +1,10 @@
-"""Per-arch smoke tests (assignment requirement): a REDUCED same-family
-variant of each of the 10 assigned architectures runs one forward/train step
-on CPU with correct output shapes and no NaNs; decode against the KV cache
-matches the full-sequence forward."""
+"""Per-arch tests (assignment requirement): a REDUCED same-family variant of
+each of the 10 assigned architectures runs through `SplitModel` — the one
+model assembly — on CPU: forward on both routes with correct output shapes
+and no NaNs, one train step, and decode against the KV cache matching the
+full-sequence forward, soft prompt included."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,10 +12,23 @@ import pytest
 
 from repro.configs import ASSIGNED, get_config
 from repro.core import losses
-from repro.models.transformer import Transformer
+from repro.core.split import SplitConfig, SplitModel
 from repro.optim import apply_updates, sgd
 
 KEY = jax.random.PRNGKey(0)
+SPLIT = SplitConfig(head_cycles=1, tail_cycles=1, prompt_len=4,
+                    prune_gamma=0.5, local_epochs=2)
+P = SPLIT.prompt_len
+
+
+def build(arch):
+    cfg = get_config(arch).reduced(n_layers=4)
+    # reduced() keeps >= 1 cycle; ensure enough cycles for a 1/1/≥1 split
+    if cfg.n_cycles < 3:
+        cyc = len(cfg.layer_pattern)
+        cfg = dataclasses.replace(
+            cfg, n_layers=cfg.n_dense_layers + 3 * cyc)
+    return cfg, SplitModel(cfg, SPLIT)
 
 
 def make_batch(cfg, B=2, S=24, with_labels=False, seed=0):
@@ -36,15 +52,13 @@ def make_batch(cfg, B=2, S=24, with_labels=False, seed=0):
 
 
 @pytest.mark.parametrize("arch", ASSIGNED)
-def test_reduced_smoke_forward(arch):
-    cfg = get_config(arch).reduced()
-    model = Transformer(cfg)
+@pytest.mark.parametrize("route", ["split", "local"])
+def test_reduced_smoke_forward(route, arch):
+    cfg, model = build(arch)
     params = model.init(KEY)
-    batch = make_batch(cfg)
-    out = model.apply(params, batch, mode="train")
-    B = 2
-    T = out["logits"].shape[1]
-    assert out["logits"].shape[0] == B
+    batch = make_batch(cfg, with_labels=True)
+    out = model.forward(params, batch, route=route, mode="train")
+    assert out["logits"].shape[0] == 2
     assert out["logits"].shape[-1] == (cfg.num_classes or cfg.vocab_size)
     assert bool(jnp.all(jnp.isfinite(out["logits"])))
 
@@ -53,14 +67,13 @@ def test_reduced_smoke_forward(arch):
 def test_reduced_smoke_train_step(arch):
     """One SGD step decreases (or at least computes) the LM/classifier loss
     with finite grads."""
-    cfg = get_config(arch).reduced()
-    model = Transformer(cfg)
+    cfg, model = build(arch)
     params = model.init(KEY)
     batch = make_batch(cfg, with_labels=True)
     opt = sgd(1e-2)
 
     def loss_fn(p):
-        out = model.apply(p, batch, mode="train")
+        out = model.forward(p, batch, route="split", mode="train")
         loss, _ = losses.task_loss(cfg, out, batch, impl="ref")
         return loss
 
@@ -73,12 +86,12 @@ def test_reduced_smoke_train_step(arch):
     assert bool(jnp.isfinite(loss1))
 
 
-@pytest.mark.parametrize("arch", [a for a in ASSIGNED])
+@pytest.mark.parametrize("arch", ASSIGNED)
 def test_decode_matches_full(arch):
-    cfg = get_config(arch).reduced()
-    if cfg.arch_type == "vit":
-        pytest.skip("classifier: no decode")
-    model = Transformer(cfg)
+    """Prefill + one decode step == the full forward's last position. The
+    soft prompt is prepended, so decode's `pos` counts it (whisper-base's
+    sinusoidal positions must count it the same way in both)."""
+    cfg, model = build(arch)
     params = model.init(KEY)
     B, S = 2, 16
     toks = jax.random.randint(KEY, (B, S + 1), 0, cfg.vocab_size)
@@ -95,12 +108,13 @@ def test_decode_matches_full(arch):
                                             cfg.d_model))
         bf["frames"] = fr
         bp["frames"] = fr
-    full = model.apply(params, bf, mode="train")["logits"]
+    full = model.forward(params, bf, route="split", mode="train")["logits"]
     cache = model.init_cache(B, seq_len=64)
-    pre = model.apply(params, bp, mode="prefill", cache=cache)
-    dec = model.apply(params, {"tokens": toks[:, S:S + 1],
-                               "pos": jnp.full((B,), S + extra, jnp.int32)},
-                      mode="decode", cache=pre["cache"])
+    pre = model.forward(params, bp, route="split", mode="prefill",
+                        cache=cache)
+    pos = jnp.full((B,), S + P + extra, jnp.int32)
+    dec = model.forward(params, {"tokens": toks[:, S:S + 1], "pos": pos},
+                        route="split", mode="decode", cache=pre["cache"])
     np.testing.assert_allclose(
         np.asarray(dec["logits"][:, 0]), np.asarray(full[:, -1]),
         rtol=2e-4, atol=2e-4)
@@ -109,69 +123,80 @@ def test_decode_matches_full(arch):
 def test_ring_buffer_decode_matches_full_window():
     """gemma2 sliding-window decode with a ring-buffer cache smaller than
     the sequence == full-cache decode (the window hides the difference)."""
-    cfg = get_config("gemma2-9b").reduced()
-    model = Transformer(cfg)
+    cfg, model = build("gemma2-9b")
     params = model.init(KEY)
     B, S = 1, 40
     toks = jax.random.randint(KEY, (B, S + 1), 0, cfg.vocab_size)
-    full_cache = model.init_cache(B, seq_len=S + 1)
-    ring_cache = model.init_cache(B, seq_len=S + 1,
+    full_cache = model.init_cache(B, seq_len=P + S + 1)
+    ring_cache = model.init_cache(B, seq_len=P + S + 1,
                                   window=cfg.attention.sliding_window)
     outs = []
     for cache in (full_cache, ring_cache):
-        pre = model.apply(params, {"tokens": toks[:, :S]}, mode="prefill",
-                          cache=cache)
-        dec = model.apply(params, {"tokens": toks[:, S:S + 1],
-                                   "pos": jnp.full((B,), S, jnp.int32)},
-                          mode="decode", cache=pre["cache"])
+        pre = model.forward(params, {"tokens": toks[:, :S]}, route="split",
+                            mode="prefill", cache=cache)
+        dec = model.forward(params, {"tokens": toks[:, S:S + 1],
+                                     "pos": jnp.full((B,), P + S, jnp.int32)},
+                            route="split", mode="decode", cache=pre["cache"])
         outs.append(np.asarray(dec["logits"][:, 0]))
     # local layers see identical windows; global layers differ only beyond
-    # the ring window — with S < window they are identical too
+    # the ring window — with P + S < window they are identical too
     np.testing.assert_allclose(outs[0], outs[1], rtol=2e-4, atol=2e-4)
 
 
 def test_multi_step_decode_consistency():
     """Greedy 4-step decode == teacher-forced full forward (stablelm)."""
-    cfg = get_config("stablelm-12b").reduced()
-    model = Transformer(cfg)
+    cfg, model = build("stablelm-12b")
     params = model.init(KEY)
     B, S, n_new = 1, 12, 4
     toks = jax.random.randint(KEY, (B, S + n_new), 0, cfg.vocab_size)
-    full = model.apply(params, {"tokens": toks}, mode="train")["logits"]
-    cache = model.init_cache(B, seq_len=S + n_new)
-    pre = model.apply(params, {"tokens": toks[:, :S]}, mode="prefill",
-                      cache=cache)
+    full = model.forward(params, {"tokens": toks}, route="split",
+                         mode="train")["logits"]
+    cache = model.init_cache(B, seq_len=P + S + n_new)
+    pre = model.forward(params, {"tokens": toks[:, :S]}, route="split",
+                        mode="prefill", cache=cache)
     cache = pre["cache"]
     for i in range(n_new):
-        out = model.apply(params, {"tokens": toks[:, S + i:S + i + 1],
-                                   "pos": jnp.full((B,), S + i, jnp.int32)},
-                          mode="decode", cache=cache)
+        pos = jnp.full((B,), P + S + i, jnp.int32)
+        out = model.forward(params, {"tokens": toks[:, S + i:S + i + 1],
+                                     "pos": pos},
+                            route="split", mode="decode", cache=cache)
         cache = out["cache"]
         np.testing.assert_allclose(np.asarray(out["logits"][:, 0]),
-                                   np.asarray(full[:, S + i - 1 + 1]),
+                                   np.asarray(full[:, P + S + i]),
                                    rtol=2e-4, atol=2e-4)
 
 
 def test_zamba2_weight_sharing():
-    """The shared attention block is one parameter set used at every site."""
-    cfg = get_config("zamba2-2.7b").reduced()
-    model = Transformer(cfg)
+    """The shared attention block is one parameter set used at every site
+    of a segment: each segment holds its own copy, and the stack positions
+    of that kind carry no per-layer weights."""
+    cfg, model = build("zamba2-2.7b")
     params = model.init(KEY)
-    assert "shared_attn" in params
-    # shared positions carry no per-layer weights of their own
-    for i, kind in enumerate(cfg.layer_pattern):
-        if kind == "shared_attn":
-            assert f"pos{i}" not in params["cycle"]
+    for seg in ("head", "body", "tail"):
+        assert "shared_attn" in params[seg]
+        for i, kind in enumerate(cfg.layer_pattern):
+            if kind == "shared_attn":
+                assert set(params[seg]["stack"][f"pos{i}"]) == {"_"}
 
 
 def test_param_count_sane():
-    """Analytic param_count is within 2% of the actual initialized count
-    for every full-size assigned config (drives the Table-1 cost model)."""
+    """Analytic param_count is within 2% of the initialized split model's
+    count for every full-size assigned config (drives the Table-1 cost
+    model), less what the split itself adds to the model."""
+    def count(tree):
+        return sum(int(np.prod(s.shape)) for s in jax.tree.leaves(tree))
+
     for arch in ASSIGNED:
         cfg = get_config(arch)
-        model = Transformer(cfg)
-        shapes = jax.eval_shape(model.init, KEY)
-        actual = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
+        shapes = jax.eval_shape(SplitModel(cfg, SPLIT).init, KEY)
+        actual = count(shapes)
+        actual -= count(shapes["prompt"])              # the soft prompt
+        if "shared_attn" in cfg.layer_pattern:
+            # one copy of the shared block in each of head, body and tail
+            actual -= 2 * count(shapes["tail"]["shared_attn"])
+        if cfg.tie_embeddings:
+            # the tail holds its own output layer; the tied model has none
+            actual -= count(shapes["tail"]["head"])
         analytic = cfg.param_count()
         assert abs(actual - analytic) / actual < 0.02, \
             (arch, actual, analytic)
